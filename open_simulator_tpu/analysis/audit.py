@@ -87,9 +87,8 @@ def run_semantic_audit(
     report_root: Optional[str] = None,
 ) -> SemanticAuditReport:
     """Run the requested passes. The race pass is pure-AST; the invariant
-    and memory passes import jax and trace/lower the registered entries —
-    callers that need a deterministic platform should run
-    ``ensure_platform()`` first (the CLI does)."""
+    and memory passes import jax and trace/lower the registered entries
+    on the platform ``JAX_PLATFORMS`` names."""
     race_report = (
         run_races(package_root=package_root, report_root=report_root)
         if races

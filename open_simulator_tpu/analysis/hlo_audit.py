@@ -6,9 +6,8 @@ construction) is re-lowered **abstractly** at each node-ladder rung ×
 mesh shape and compiled without ever executing, then three things are
 extracted from the compiled artifact:
 
-* **memory** — per-device argument/output/temp/alias bytes from
-  ``compiled.memory_analysis()`` (peak derived as arg+out+temp−alias;
-  jax 0.4.37 reports no peak field), cross-checked against the
+* **memory** — per-device argument/output/temp/alias/peak bytes from
+  ``compiled.memory_analysis()``, cross-checked against the
   shape-arithmetic estimator in ``analysis.budget`` so the estimator —
   which also backs ``parallel.mesh.hbm_bytes_per_device`` for
   unmaterialized trees — is continuously proven against XLA's own
@@ -102,7 +101,41 @@ FIXED_SHAPE = frozenset({
 
 DEFAULT_RUNGS: Tuple[int, ...] = (64, 128)
 DEFAULT_MESHES: Tuple[str, ...] = ("1", "2x1", "2x2")
-DEFAULT_HBM_GIB = 32.0  # one v4/v5p-class chip's HBM
+#: HBM per chip by ``jax.Device.device_kind``, in bytes. Source: Google
+#: Cloud TPU documentation, system-architecture pages ("TPU v5e": 16 GB
+#: HBM per chip; "TPU v4": 32 GiB HBM per chip). An unknown kind is an
+#: error: no figure is assumed for a device nobody looked up.
+HBM_BYTES_BY_KIND: Dict[str, int] = {
+    "TPU v5 lite": 16 * 10 ** 9,
+    "TPU v4": 32 * 1024 ** 3,
+}
+
+#: the chip this repository targets: the preflight verdict, compiled on
+#: virtual CPU devices, is judged against its HBM
+TARGET_DEVICE_KIND = "TPU v5 lite"
+
+
+def device_hbm_bytes(device: Any = None) -> int:
+    """Per-device HBM for the verdict. A real accelerator reports its own
+    ``memory_stats()["bytes_limit"]``; on the CPU (virtual devices) the
+    figure is the target chip's from HBM_BYTES_BY_KIND. Raises KeyError
+    for a device kind the table does not know."""
+    import jax
+
+    device = device if device is not None else jax.devices()[0]
+    if device.platform != "cpu":
+        stats = device.memory_stats() or {}
+        if stats.get("bytes_limit"):
+            return int(stats["bytes_limit"])
+        kind = device.device_kind
+    else:
+        kind = TARGET_DEVICE_KIND
+    if kind not in HBM_BYTES_BY_KIND:
+        raise KeyError(
+            f"no HBM figure for device kind {kind!r} "
+            f"(known: {sorted(HBM_BYTES_BY_KIND)}); pass --hbm-gib"
+        )
+    return HBM_BYTES_BY_KIND[kind]
 
 #: Cross-check tolerance: the estimator must agree with memory_analysis()
 #: within this envelope (XLA adds tuple/alignment padding the shape
@@ -438,14 +471,7 @@ def audit_program(
         pa.output_bytes = int(ma.output_size_in_bytes)
         pa.temp_bytes = int(ma.temp_size_in_bytes)
         pa.alias_bytes = int(ma.alias_size_in_bytes)
-        # jax 0.4.37's CompiledMemoryStats has no peak field on CPU; the
-        # simultaneously-live upper bound is args + outputs + temps minus
-        # donated aliases (donated inputs are reused as outputs).
-        pa.peak_bytes = max(
-            0,
-            pa.argument_bytes + pa.output_bytes + pa.temp_bytes
-            - pa.alias_bytes,
-        )
+        pa.peak_bytes = int(ma.peak_memory_in_bytes)
 
         # estimator cross-check: the budget arithmetic must reproduce
         # XLA's per-device accounting from shapes alone (memory_analysis
@@ -564,14 +590,20 @@ def transfer_audit(caps: Sequence[Any]) -> List[TransferCheck]:
 
 def plan_verdict(
     caps: Sequence[Any],
-    hbm_gib: float = DEFAULT_HBM_GIB,
+    hbm_gib: Optional[float] = None,
     tables: Optional[tuple] = None,
 ) -> dict:
     """The machine-checked headline: does `plan_1m_100k`'s scenario
     program (1M pods -> pod bucket, 100k nodes -> rung 102400) fit
     per-device HBM on a 1×4 node-sharded mesh with the node table proven
-    sharded (zero full-rung gathers)? Purely from the lowered program."""
+    sharded (zero full-rung gathers)? Purely from the lowered program.
+    ``hbm_gib`` None: the budget is device_hbm_bytes()."""
     from ..ops.encode import node_bucket, round_up
+
+    gib = 1024 ** 3
+    hbm_bytes = (
+        device_hbm_bytes() if hbm_gib is None else int(hbm_gib * gib)
+    )
 
     rung = node_bucket(100_000)
     pods = round_up(1_000_000)
@@ -582,7 +614,7 @@ def plan_verdict(
         "rung": rung,
         "pod_bucket": pods,
         "mesh": "1x4",
-        "hbm_gib": float(hbm_gib),
+        "hbm_gib": round(hbm_bytes / gib, 3),
     }
     if cap is None:
         verdict["error"] = "schedule_scenarios not in capture registry"
@@ -598,7 +630,6 @@ def plan_verdict(
         verdict["ok"] = False
         return verdict
     pa = audit_program(cap, rung, "1x4", tables=tables, pod_bucket=pods)
-    gib = 1024 ** 3
     verdict.update(
         peak_bytes=pa.peak_bytes,
         peak_gib=round(pa.peak_bytes / gib, 3),
@@ -609,7 +640,7 @@ def plan_verdict(
         collectives=pa.collectives,
         node_gathers=pa.node_gathers,
         node_table_sharded=not pa.node_gathers,
-        fits=pa.peak_bytes <= int(hbm_gib * gib),
+        fits=pa.peak_bytes <= hbm_bytes,
         compile_seconds=round(pa.seconds, 2),
         error=pa.error,
     )
@@ -765,7 +796,7 @@ def run_preflight(
     book: Optional[BudgetBook] = None,
     transfers: bool = True,
     verdict: bool = True,
-    hbm_gib: float = DEFAULT_HBM_GIB,
+    hbm_gib: Optional[float] = None,
 ) -> PreflightReport:
     """The full preflight: capture registry -> (entry × rung × mesh)
     abstract compile matrix -> budget diff -> transfer audit -> plan

@@ -624,8 +624,9 @@ class Interpreter:
                   alias: Optional[Dict] = None,
                   env_out: Optional[Dict] = None) -> List[AVal]:
         import jax
+        import jax.extend.core
 
-        literal_t = jax.core.Literal
+        literal_t = jax.extend.core.Literal
         dropvar_t = getattr(jax.core, "DropVar", ())
         env: Dict = {}
         scope = _Scope()
@@ -664,7 +665,7 @@ class Interpreter:
         if name in SELECTION_PRIMITIVES:
             self._check_selection(eqn, ins, path)
 
-        if name == "pjit":
+        if name == "jit":
             sub = eqn.params["jaxpr"]
             child_alias = {
                 v: (scope, a)
@@ -719,9 +720,10 @@ class Interpreter:
         """Canonical (atom, scope) pair: look through broadcast/reshape/copy
         chains and across pjit boundaries via the scope alias links."""
         import jax
+        import jax.extend.core
 
         while True:
-            if isinstance(atom, jax.core.Literal) or scope is None:
+            if isinstance(atom, jax.extend.core.Literal) or scope is None:
                 return atom, None
             d = scope.def_of.get(atom)
             if d is not None and d.primitive.name in (
@@ -740,6 +742,7 @@ class Interpreter:
         Returns the case index to mark nonzero when the branch operand is
         the compared variable and the comparand is exactly 0."""
         import jax
+        import jax.extend.core
 
         if len(eqn.invars) != 3:
             return None
@@ -756,7 +759,7 @@ class Interpreter:
 
         def lit_zero(res) -> bool:
             a = res[0]
-            return isinstance(a, jax.core.Literal) and bool(
+            return isinstance(a, jax.extend.core.Literal) and bool(
                 np.all(np.asarray(a.val) == 0)
             )
 
@@ -815,6 +818,7 @@ class Interpreter:
         docstring: structural recognition of the commit idiom, with the
         amount bound on *guarded* slots discharged by ``simon prove``."""
         import jax
+        import jax.extend.core
 
         body = eqn.params["jaxpr"].jaxpr
         n_const = eqn.params["num_consts"]
@@ -831,7 +835,7 @@ class Interpreter:
             if kind_of(cv.aval.dtype) != "f":
                 continue
             verdict, detail = self._classify_carry_slot(
-                defs, env, cv, ov, jax.core.Literal
+                defs, env, cv, ov, jax.extend.core.Literal
             )
             if verdict == CARRY_UNGUARDED and isinstance(
                 eqn.outvars[slot], dropvar_t
@@ -1174,11 +1178,30 @@ class EntryInvariantReport:
         }
 
 
+def _dynamic_leaves(fn, args: tuple, kwargs: dict) -> list:
+    """The jit's flat dynamic args, in invar order: (args, kwargs) leaves
+    with the static args (``__osim_static_argnames__`` from @sanitizable)
+    dropped, as jit itself drops them before flattening."""
+    import inspect
+
+    import jax
+
+    static = set(getattr(fn, "__osim_static_argnames__", ()) or ())
+    if static:
+        params = list(inspect.signature(fn).parameters)
+        args = tuple(
+            a for i, a in enumerate(args)
+            if i >= len(params) or params[i] not in static
+        )
+        kwargs = {k: v for k, v in kwargs.items() if k not in static}
+    return jax.tree_util.tree_leaves((args, kwargs))
+
+
 def check_traceable(entry: str, fn, args, kwargs=None) -> EntryInvariantReport:
     """Abstractly interpret one traceable callable on concrete args.
 
-    Uses `.trace()` when `fn` is a jit Function (exact invar<->arg mapping
-    via the Traced's flat args) and `jax.make_jaxpr` otherwise.
+    Uses `.trace()` when `fn` is a jit Function (invars are its dynamic
+    args, see _dynamic_leaves) and `jax.make_jaxpr` otherwise.
     """
     import jax
 
@@ -1186,7 +1209,12 @@ def check_traceable(entry: str, fn, args, kwargs=None) -> EntryInvariantReport:
     if hasattr(fn, "trace"):
         traced = fn.trace(*args, **kwargs)
         closed = traced.jaxpr
-        flat = traced._args_flat
+        flat = _dynamic_leaves(fn, args, kwargs)
+        if len(flat) != len(closed.jaxpr.invars):
+            raise ValueError(
+                f"{entry}: {len(flat)} dynamic args for "
+                f"{len(closed.jaxpr.invars)} invars (static args undeclared?)"
+            )
     else:
         closed = jax.make_jaxpr(fn)(*args, **kwargs)
         flat = jax.tree_util.tree_leaves((args, kwargs))

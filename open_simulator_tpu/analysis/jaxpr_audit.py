@@ -424,14 +424,16 @@ def _capture_calls() -> List[_Captured]:
         # the batched scenario engine (`schedule_scenarios`): a 2-lane
         # what-if sweep padded to the scenario bucket, the exact shapes
         # Simulator.run_scenarios ships (lane 1 masks off half the nodes;
-        # pad lanes are copies of lane 0, as in production)
+        # pad lanes are copies of lane 0, as in production). Called
+        # directly, not through schedule_scenarios_host, whose wave/chunk
+        # routing depends on the backend and the host's core count.
         s_pad = fast.scenario_bucket(2)
         valid_s = jnp.stack([ns.valid] * s_pad)
         valid_s = valid_s.at[1, 12:].set(False)
         weights_s = jnp.stack([weights] * s_pad)
-        fast.schedule_scenarios_host(
-            ns, state_mod.stack_carry(carry, s_pad), batch,
-            weights_s, valid_s, 2,
+        fast.schedule_scenarios(
+            ns, state_mod.stack_carry(carry, s_pad), rows,
+            weights_s, valid_s, None,
         )
         # the chunked commit driver (`schedule_scenarios_chunked`,
         # OSIM_COMMIT_CHUNK > 0): one count-gated chunk at the same lane
@@ -895,7 +897,8 @@ def warm_start_check() -> WarmStartResult:
 
     Run this after `simon warmup` — in the same process (warmup's sweep
     rehearsal filled the in-process jit caches) or a later one sharing
-    OSIM_COMPILE_CACHE (every compile request must then persistent-hit).
+    its compilation cache dir (every compile request must then
+    persistent-hit).
     Either way a nonzero cold count means some program the sweep needs was
     not banked, i.e. the production run would pay a compile inside its
     capture window."""

@@ -123,7 +123,7 @@ def _run_runs(args) -> int:
         hdr = f"{'RUN':<28} {'KIND':<6} {'STATUS':<18} {'TRIALS':>6} {'SEGS':>4} {'DEVICE':<14} PATH"
         print(hdr)
         for r in rows:
-            flag = " (cpu-fallback)" if r["fallback"] == "cpu" else ""
+            flag = " (backend error)" if r["backend_error"] else ""
             print(
                 f"{r['name']:<28} {r['kind']:<6} {r['status']:<18} "
                 f"{r['trials']:>6} {r['segments']:>4} "
@@ -146,7 +146,7 @@ def _run_runs(args) -> int:
                               indent=2, sort_keys=True))
             return 0
         for k in ("run_dir", "kind", "config", "status", "outcome", "device",
-                  "fallback", "events", "trials", "segments", "resumes",
+                  "backend_error", "events", "trials", "segments", "resumes",
                   "watchdogs"):
             print(f"{k:>10}: {summary[k]}")
         print("journal:")
@@ -644,17 +644,16 @@ def _fault_plan_doc(plan) -> dict:
 def _run_chaos_capacity(args, plan) -> int:
     """`simon chaos --capacity`: the mid-plan-kill scenario.
 
-    Three legs: (1) a clean in-process chunked capacity sweep banks the
-    reference placement digest; (2) the same sweep runs journaled in a
-    subprocess under the fault plan — `chunk_kill` SIGKILLs it mid-chunk
+    Three legs, each a `simon sweep --capacity` child, so that the parent
+    never touches the device and each child can hold the chip: (1) a clean
+    journaled sweep banks the reference placement digest; (2) the same
+    sweep runs under the fault plan — `chunk_kill` SIGKILLs it mid-chunk
     (the child cannot report anything; its journal and snapshots are the
     evidence), `device_lost` is recovered inside the child from its last
-    good carry; (3) a killed run is resumed in-process (faults OFF —
-    resume must work on a healthy host) and the final placement digest is
-    compared byte-for-byte with the reference. Degraded-not-failed means:
-    faults fired, the plan still landed, and the digests match (exit 0)."""
-    import contextlib as _ctx
-    import io as _io
+    good carry; (3) a killed run is resumed with faults OFF (resume must
+    work on a healthy host) and the final placement digest is compared
+    byte-for-byte with the reference. Degraded-not-failed means: faults
+    fired, the plan still landed, and the digests match (exit 0)."""
     import json as _json
     import os as _os
     import shutil as _shutil
@@ -663,18 +662,7 @@ def _run_chaos_capacity(args, plan) -> int:
 
     import yaml as _yaml
 
-    from ..api.config import SimonConfig
-    from ..engine.apply import (
-        ApplyError,
-        build_apps,
-        build_cluster,
-        load_new_node,
-        placement_digest,
-    )
-    from ..engine.capacity import plan_capacity
     from ..durable import replay
-    from ..resilience.policy import reset_breakers
-    from ..utils import metrics
 
     chunk = _os.environ.get("OSIM_COMMIT_CHUNK", "").strip() or "8"
     every = _os.environ.get("OSIM_CKPT_EVERY", "").strip() or "2"
@@ -684,93 +672,79 @@ def _run_chaos_capacity(args, plan) -> int:
     # reference. OSIM_WAVE_COMMIT=0 in the environment keeps the serial
     # chunked driver for comparison.
     wave = _os.environ.get("OSIM_WAVE_COMMIT", "").strip() or "1"
-    metrics.REGISTRY.reset()
-    reset_breakers()
 
     run_dir = args.run_dir or _tf.mkdtemp(prefix="simon-chaos-capacity-")
     cleanup = not args.run_dir
-    saved = {
-        k: _os.environ.get(k)
-        for k in ("OSIM_COMMIT_CHUNK", "OSIM_CKPT_EVERY", "OSIM_WAVE_COMMIT")
-    }
-    _os.environ["OSIM_COMMIT_CHUNK"] = chunk
-    _os.environ["OSIM_CKPT_EVERY"] = every
-    _os.environ["OSIM_WAVE_COMMIT"] = wave
-    try:
+    ref_dir = _tf.mkdtemp(prefix="simon-chaos-reference-")
+    env = dict(_os.environ)
+    env.pop("OSIM_FAULT_PLAN", None)
+    env.update(
+        OSIM_COMMIT_CHUNK=chunk, OSIM_CKPT_EVERY=every,
+        OSIM_WAVE_COMMIT=wave,
+    )
+
+    def sweep(out_dir: str, *extra: str, fault_plan: str = "") -> int:
+        e = dict(env, OSIM_FLIGHT_DIR=out_dir)
+        if fault_plan:
+            e["OSIM_FAULT_PLAN"] = fault_plan
+        return _sp.run(
+            [sys.executable, "-m", "open_simulator_tpu.cli.main", "sweep",
+             "--capacity", "-f", args.simon_config, "--run-dir", out_dir,
+             *extra],
+            env=e, stdout=_sp.DEVNULL, stderr=_sp.DEVNULL,
+        ).returncode
+
+    def digest_of(out_dir: str) -> str:
         try:
-            cfg = SimonConfig.load(args.simon_config)
-            cluster = build_cluster(cfg)
-            apps = build_apps(cfg)
-            new_node = load_new_node(cfg)
-        except (ApplyError, ValueError, OSError) as e:
-            print(f"error: {e}", file=sys.stderr)
+            with open(_os.path.join(out_dir, "outcome.json")) as fh:
+                return str(_json.load(fh).get("placement_digest", ""))
+        except (OSError, ValueError):
+            return ""
+
+    try:
+        if not _os.path.exists(args.simon_config):
+            print(f"error: config not found: {args.simon_config}",
+                  file=sys.stderr)
             return 1
-        if new_node is None:
+        rc = sweep(ref_dir)
+        ref_digest = digest_of(ref_dir)
+        if rc != 0 or not ref_digest:
             print(
-                "error: chaos --capacity needs a newNode candidate in the "
-                "config", file=sys.stderr,
-            )
-            return 1
-        ref = plan_capacity(cluster, apps, new_node, sweep_mode="batched")
-        if ref is None:
-            print(
-                "error: reference capacity sweep found no fitting plan",
+                f"error: reference capacity sweep failed (rc {rc}); it "
+                "needs a newNode candidate and a plan that fits",
                 file=sys.stderr,
             )
             return 1
-        ref_digest = placement_digest(ref.result)
 
+        _os.makedirs(run_dir, exist_ok=True)
         plan_path = _os.path.join(run_dir, "fault-plan.yaml")
         with open(plan_path, "w") as fh:
             _yaml.safe_dump(_fault_plan_doc(plan), fh, sort_keys=True)
-        env = dict(_os.environ)
-        env["OSIM_FAULT_PLAN"] = plan_path
-        child = _sp.run(
-            [sys.executable, "-m", "open_simulator_tpu.cli.main", "sweep",
-             "--capacity", "-f", args.simon_config, "--run-dir", run_dir],
-            env=env, stdout=_sp.DEVNULL, stderr=_sp.DEVNULL,
-        )
-        killed = child.returncode in (137, -9)
+        rc = sweep(run_dir, fault_plan=plan_path)
+        killed = rc in (137, -9)
         if killed:
-            import argparse as _argparse
-
-            with _ctx.redirect_stdout(_io.StringIO()):
-                rc = _run_sweep(_argparse.Namespace(
-                    simon_config=args.simon_config, capacity=True,
-                    node_counts="", use_greed=False, format="text",
-                    run_dir=run_dir, resume=True,
-                ))
+            rc = sweep(run_dir, "--resume")
             if rc != 0:
                 print(
                     f"error: resume of the killed sweep failed (rc {rc})",
                     file=sys.stderr,
                 )
                 return 1
-        elif child.returncode != 0:
+        elif rc != 0:
             print(
-                f"error: faulted sweep exited rc {child.returncode} "
+                f"error: faulted sweep exited rc {rc} "
                 "(expected 0, or SIGKILL from a chunk_kill rule)",
                 file=sys.stderr,
             )
             return 1
-
-        try:
-            with open(_os.path.join(run_dir, "outcome.json")) as fh:
-                outcome = _json.load(fh)
-        except (OSError, ValueError):
-            outcome = {}
-        got_digest = str(outcome.get("placement_digest", ""))
+        got_digest = digest_of(run_dir)
 
         events = replay(run_dir)
         n_chunk_records = sum(
             1 for e in events if e.get("event") == "plan_chunk"
         )
 
-        def total(counter) -> int:
-            snap = counter.snapshot()
-            return int(sum(s["value"] for s in snap["samples"]))
-
-        skipped = total(metrics.RESUME_CHUNKS_SKIPPED)
+        skipped = 0
         art_kinds: dict = {}
         last_note = None
         for name in sorted(_os.listdir(run_dir)):
@@ -783,9 +757,16 @@ def _run_chaos_capacity(args, plan) -> int:
                 continue
             reason = str(doc.get("reason", "?"))
             art_kinds[reason] = art_kinds.get(reason, 0) + 1
+            restored = None
             for ev in doc.get("events", []):
                 if ev.get("kind") in ("plan-restore", "device-lost"):
                     last_note = ev
+                if ev.get("kind") == "plan-restore":
+                    restored = ev
+            # each resume restore dumps one artifact right after noting the
+            # last chunk its snapshot covers
+            if reason == "chunk-restore" and restored is not None:
+                skipped += int(restored.get("chunk", -1)) + 1
 
         lines = ["simon chaos report", "=================="]
         lines.append(f"fault plan: seed={plan.seed}, {len(plan.rules)} rule(s)")
@@ -849,11 +830,7 @@ def _run_chaos_capacity(args, plan) -> int:
         print("\n".join(lines))
         return 0
     finally:
-        for k, v in saved.items():
-            if v is None:
-                _os.environ.pop(k, None)
-            else:
-                _os.environ[k] = v
+        _shutil.rmtree(ref_dir, ignore_errors=True)
         if cleanup:
             _shutil.rmtree(run_dir, ignore_errors=True)
 
@@ -899,13 +876,9 @@ def _run_audit(args) -> int:
     from ..analysis.audit import run_semantic_audit
 
     if not args.no_invariants or args.memory:
-        # the invariant and memory passes trace jitted entries — pin the
-        # platform the same way apply/server do before jax initializes
-        from ..utils.platform import ensure_platform
         from ..utils.tracing import init_logging
 
         init_logging()
-        ensure_platform()
     report = run_semantic_audit(
         races=not args.no_races,
         invariants=not args.no_invariants,
@@ -1092,13 +1065,9 @@ def _run_check(args) -> int:
     from ..analysis import sarif as sarif_mod
 
     if not args.no_invariants or not args.no_preflight:
-        # these passes trace/lower jitted entries — pin the platform the
-        # same way `simon audit` / `simon preflight` do
-        from ..utils.platform import ensure_platform
         from ..utils.tracing import init_logging
 
         init_logging()
-        ensure_platform()
 
     runs = []
     texts = []
@@ -1225,8 +1194,9 @@ def _add_preflight(sub: argparse._SubParsersAction) -> None:
         help="skip the plan_1m_100k fits-in-HBM verdict compile",
     )
     p.add_argument(
-        "--hbm-gib", type=float, default=32.0,
-        help="per-device HBM budget for the verdict (default: 32 GiB)",
+        "--hbm-gib", type=float, default=None,
+        help="per-device HBM budget for the verdict (default: the chip's "
+        "own bytes_limit; on the CPU, a TPU v5e chip's 16 GB)",
     )
 
 
@@ -1362,7 +1332,8 @@ def _add_warmup(sub: argparse._SubParsersAction) -> None:
             "over) at their canonical bucketed shapes, drive each through "
             "trace().lower().compile(), and rehearse the full capacity "
             "sweep so every program the engine needs lands in the "
-            "persistent compilation cache (OSIM_COMPILE_CACHE) before "
+            "persistent compilation cache (JAX_COMPILATION_CACHE_DIR, "
+            "else <checkout>/.jax_cache) before "
             "anything is being timed or deadlined. A later process "
             "sharing the cache then pays zero cold compiles — "
             "`simon warmup --check` asserts exactly that and exits "
@@ -1384,7 +1355,7 @@ def _add_warmup(sub: argparse._SubParsersAction) -> None:
         help="run the warm-start check instead of warming: re-run the "
         "full capacity sweep and demand ZERO cold compiles (exit 1 "
         "otherwise); run after `simon warmup` in a process sharing "
-        "OSIM_COMPILE_CACHE",
+        "its compilation cache directory",
     )
 
 
@@ -1513,9 +1484,6 @@ def _run_lint(args) -> int:
     report = run_lint(only_rules=only)
     audit = guard = None
     if not args.no_jaxpr:
-        from ..utils.platform import ensure_platform
-
-        ensure_platform()
         from ..analysis.jaxpr_audit import run_audit, run_recompile_guard
 
         audit = run_audit()
@@ -1625,12 +1593,15 @@ def main(argv=None) -> int:
         "apply", "chaos", "server", "runs", "sweep", "warmup", "preflight",
         "profile", "prove",
     ):
-        from ..utils.platform import enable_compilation_cache, ensure_platform
+        from ..utils.platform import (
+            enable_compilation_cache,
+            install_compile_listener,
+        )
         from ..utils.tracing import init_logging
 
         init_logging()  # LogLevel env, parity: cmd/simon/simon.go:46-66
-        ensure_platform()
         enable_compilation_cache()
+        install_compile_listener()
         # crash flight recorder: any unhandled crash of a device-touching
         # command dumps the recent-span/metric/journal ring first
         # (utils/flightrec.py; idempotent, safe under nested main() calls)
@@ -1692,6 +1663,7 @@ def main(argv=None) -> int:
         )
     if args.command == "apply":
         from ..api.config import SimonConfig
+        from ..durable import BackendUnavailable, DeadlineExceeded
         from ..engine.apply import ApplyError, run_apply
 
         try:
@@ -1739,7 +1711,10 @@ def main(argv=None) -> int:
 
                 with open(args.metrics_file, "w") as fh:
                     json.dump(REGISTRY.snapshot(), fh, indent=2)
-        except (ApplyError, ValueError, OSError) as e:
+        except (
+            ApplyError, ValueError, OSError, BackendUnavailable,
+            DeadlineExceeded,
+        ) as e:
             print(f"error: {e}", file=sys.stderr)
             return 1
         return 0 if not outcome.result.unscheduled else 2

@@ -3,8 +3,8 @@
 `journal` is the per-run write-ahead log (JSONL, fsync per record) that
 lets a crashed/preempted capacity sweep or bench ladder resume from its
 committed trials; `watchdog` puts hard deadlines around backend
-acquisition and blocking device calls and degrades TPU→CPU with honest
-top-level provenance instead of hanging. See docs/durability.md.
+acquisition and blocking device calls, so a backend that fails or hangs
+fails the run loudly, naming the platform. See docs/durability.md.
 """
 
 from .checkpoint import (  # noqa: F401
@@ -24,6 +24,7 @@ from .journal import (  # noqa: F401
     summarize_run,
 )
 from .watchdog import (  # noqa: F401
+    BackendUnavailable,
     DeadlineExceeded,
     acquire_backend,
     backend_deadline_s,

@@ -154,13 +154,9 @@ class ApplyOutcome:
     plan: Optional[CapacityPlan] = None
     report: str = ""
     failed_apps: List[FailedApp] = dataclass_field(default_factory=list)
-    # Honest device provenance (durable/watchdog.py ladder): which backend
-    # actually ran the simulation, and — when the run degraded — why. These
-    # are stamped as TOP-LEVEL fields of every serialized outcome so a
-    # CPU-fallback run can never masquerade as a TPU capture.
+    # the device the simulation ran on (durable/watchdog.acquire_backend),
+    # stamped as a TOP-LEVEL field of every serialized outcome
     device: str = ""
-    fallback: str = ""
-    fallback_reason: str = ""
 
 
 def placement_digest(result: SimulateResult) -> str:
@@ -251,12 +247,11 @@ def run_apply(
     acquisition, every capacity trial, and the final outcome are committed
     as they happen, and `resume=True` replays the journal so a crashed run
     re-simulates only what it never finished. Without `run_dir` the run is
-    un-journaled but still acquires its backend through the watchdog ladder
-    and stamps honest device provenance on the outcome."""
+    un-journaled but still acquires its backend under the watchdog and
+    stamps the device on the outcome."""
     import sys
 
     from ..durable import (
-        DeadlineExceeded,
         RunJournal,
         acquire_backend,
         atomic_write,
@@ -361,29 +356,10 @@ def run_apply(
                     result = plan.result
         return result, plan
 
-    try:
-        result, plan = _simulate_and_plan(resume)
-    except DeadlineExceeded as e:
-        # A guarded device call wedged mid-run (the r03–r05 failure mode,
-        # post-acquisition flavor). Degrade to CPU explicitly, stamp the
-        # provenance, and retry once — resuming from the journal so trials
-        # the wedged attempt already committed are not re-simulated.
-        reason = f"guarded call wedged mid-run: {e}"
-        print(f"watchdog: {e}; degrading to CPU and retrying", file=ui_out)
-        os.environ["JAX_PLATFORMS"] = "cpu"
-        import jax
-
-        jax.config.update("jax_platforms", "cpu")
-        backend.update(
-            device=str(jax.devices()[0]), fallback="cpu",
-            fallback_reason=reason,
-        )
-        if journal is not None:
-            journal.append(
-                "backend_fallback", device=backend["device"], fallback="cpu",
-                fallback_reason=reason,
-            )
-        result, plan = _simulate_and_plan(journal is not None)
+    # a guarded call that outlives OSIM_CALL_DEADLINE_S raises
+    # DeadlineExceeded (journaled as `watchdog`) and fails the run; a
+    # `--resume` of the same run dir skips the trials already committed
+    result, plan = _simulate_and_plan(resume)
 
     with span("render-report"):
         report = full_report(result, extended_resources=extended_resources)
@@ -405,12 +381,7 @@ def run_apply(
 
         display = colorize_report(report)
     print(display, file=out)
-    device_line = f"device: {backend.get('device', '')}"
-    if backend.get("fallback"):
-        device_line += (
-            f" (fallback={backend['fallback']}: {backend['fallback_reason']})"
-        )
-    print(device_line, file=out)
+    print(f"device: {backend.get('device', '')}", file=out)
 
     digest = placement_digest(result)
     if journal is not None:
@@ -429,8 +400,6 @@ def run_apply(
                 {
                     "outcome": outcome,
                     "device": backend.get("device", ""),
-                    "fallback": backend.get("fallback", ""),
-                    "fallback_reason": backend.get("fallback_reason", ""),
                     "nodes_added": plan.nodes_added if plan else 0,
                     "attempts": plan.attempts if plan else 0,
                     "retries": plan.retries if plan else 0,
@@ -447,8 +416,6 @@ def run_apply(
     return ApplyOutcome(
         result=result, plan=plan, report=report, failed_apps=failed_apps,
         device=backend.get("device", ""),
-        fallback=backend.get("fallback", ""),
-        fallback_reason=backend.get("fallback_reason", ""),
     )
 
 

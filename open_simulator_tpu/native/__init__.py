@@ -1,8 +1,10 @@
 """ctypes loader for the native host runtime (osim_native.cpp).
 
-Degrades gracefully: if the shared library is missing it is compiled on
-demand with g++ (the toolchain baked into the image); if that fails, every
-entry point reports unavailable and callers keep their pure-Python paths.
+Degrades gracefully: the shared library is compiled on demand with g++
+(the toolchain baked into the image); if that fails, every entry point
+reports unavailable and callers keep their pure-Python paths. The
+library's file name carries the source's hash, so a binary built from
+another version of osim_native.cpp is never loaded.
 The reference's host layer is compiled Go — this is the TPU build's
 equivalent compiled layer for host-side hot loops (SURVEY §2.4).
 """
@@ -10,6 +12,7 @@ equivalent compiled layer for host-side hot loops (SURVEY §2.4).
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -18,7 +21,6 @@ from typing import Optional, Tuple
 import numpy as np
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
-_SO = os.path.join(_DIR, "libosim_native.so")
 _SRC = os.path.join(_DIR, "osim_native.cpp")
 
 _lock = threading.Lock()
@@ -26,16 +28,32 @@ _lib: Optional[ctypes.CDLL] = None
 _tried = False
 
 
-def _build() -> bool:
+def _so_path() -> str:
+    """libosim_native.<source sha256 prefix>.so"""
+    with open(_SRC, "rb") as fh:
+        digest = hashlib.sha256(fh.read()).hexdigest()[:16]
+    return os.path.join(_DIR, f"libosim_native.{digest}.so")
+
+
+def _build(so: str) -> bool:
+    """Compile to a private temp name, then rename: a concurrent loader
+    sees either no library or a whole one."""
+    tmp = f"{so}.{os.getpid()}.tmp"
     try:
         proc = subprocess.run(
-            ["g++", "-O2", "-fPIC", "-shared", "-std=c++17", _SRC, "-o", _SO],
+            ["g++", "-O2", "-fPIC", "-shared", "-std=c++17", _SRC, "-o", tmp],
             capture_output=True,
             timeout=120,
         )
-        return proc.returncode == 0 and os.path.exists(_SO)
+        if proc.returncode != 0:
+            return False
+        os.replace(tmp, so)
+        return True
     except (OSError, subprocess.SubprocessError):
         return False
+    finally:
+        if os.path.exists(tmp):
+            os.unlink(tmp)
 
 
 def load() -> Optional[ctypes.CDLL]:
@@ -50,10 +68,14 @@ def load() -> Optional[ctypes.CDLL]:
         # with these writes, but both writes happen under _lock and a stale
         # fast-path read only costs a harmless second trip into the lock
         _tried = True  # osim: audit-ok[race]
-        if not os.path.exists(_SO) and not _build():
+        try:
+            so = _so_path()
+        except OSError:
+            return None
+        if not os.path.exists(so) and not _build(so):
             return None
         try:
-            lib = ctypes.CDLL(_SO)
+            lib = ctypes.CDLL(so)
         except OSError:
             return None
         lib.osim_hash_rows.argtypes = [

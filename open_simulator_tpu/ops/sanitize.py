@@ -80,7 +80,6 @@ def _has_tracer(args: tuple, kwargs: dict) -> bool:
 def sanitizable(
     name: str,
     static_argnames: Sequence[str] = (),
-    skip_kwargs: Sequence[str] = (),
     donate_argnums: Sequence[int] = (),
 ) -> Callable:
     """Wrap a jitted entry point with an opt-in checkify layer.
@@ -88,12 +87,7 @@ def sanitizable(
     `name` keys the osim_sanitizer_violations_total{entry=} counter and
     matches jaxpr_audit's entry naming ("ops.fast:light_scan").
     `static_argnames` must repeat the underlying jit's static args so the
-    checkified re-jit treats them identically. A truthy arg named in
-    `skip_kwargs` (e.g. domain_select's use_pallas) bypasses sanitization
-    whether it arrives by keyword or positionally: checkify cannot thread
-    pallas_call's state effects (`JaxprInputEffect ... does not have
-    corresponding input`), and the plain path already covers the shared
-    math.
+    checkified re-jit treats them identically.
 
     `donate_argnums` must repeat the underlying jit's donated positional
     args. It is declarative: the jaxpr auditor reads it (as
@@ -104,7 +98,6 @@ def sanitizable(
     failure reports; results are bit-identical either way.
     """
     static = tuple(static_argnames)
-    skips = tuple(skip_kwargs)
     donated = tuple(donate_argnums)
 
     def deco(jitted: Callable) -> Callable:
@@ -112,10 +105,6 @@ def sanitizable(
 
         cache: dict = {}
         cache_lock = threading.Lock()
-        params = list(
-            inspect.signature(inspect.unwrap(jitted)).parameters
-        )
-        skip_pos = {k: params.index(k) for k in skips if k in params}
 
         def _checked() -> Callable:
             fn = cache.get("fn")
@@ -145,10 +134,6 @@ def sanitizable(
         def wrapper(*args: Any, **kwargs: Any):
             if not sanitize_enabled():
                 return jitted(*args, **kwargs)
-            for k in skips:
-                i = skip_pos.get(k, len(args))
-                if kwargs.get(k) or (i < len(args) and args[i]):
-                    return jitted(*args, **kwargs)
             if _has_tracer(args, kwargs):
                 # already inside someone else's trace: the outer entry owns
                 # the checkify scope
@@ -165,6 +150,7 @@ def sanitizable(
         wrapper.lower = jitted.lower  # type: ignore[attr-defined]
         wrapper.__osim_sanitizable__ = name  # type: ignore[attr-defined]
         wrapper.__osim_donate_argnums__ = donated  # type: ignore[attr-defined]
+        wrapper.__osim_static_argnames__ = static  # type: ignore[attr-defined]
         return wrapper
 
     return deco

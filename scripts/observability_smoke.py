@@ -40,11 +40,9 @@ def main() -> int:
 
     from open_simulator_tpu.api.config import SimonConfig
     from open_simulator_tpu.engine.apply import run_apply
-    from open_simulator_tpu.utils.platform import ensure_platform
     from open_simulator_tpu.utils.profiling import analyze_dispatch_gaps
     from open_simulator_tpu.utils.tracing import span
 
-    ensure_platform()
     cfg = SimonConfig.load(CONFIG)
     with span("observability-smoke"):
         run_apply(cfg, out=io.StringIO())

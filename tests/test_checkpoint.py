@@ -452,3 +452,35 @@ def test_sigkill_mid_chunk_then_cli_resume(tmp_path):
     with open(os.path.join(run_dir, "outcome.json")) as fh:
         got = json.load(fh)["placement_digest"]
     assert got == want
+
+
+def test_chaos_capacity_runs_every_leg_in_a_child(tmp_path, capsys,
+                                                  monkeypatch):
+    """`simon chaos --capacity`: the clean reference, the faulted sweep and
+    the resume each run in a child (one process per chip); the resumed plan
+    byte-matches the reference."""
+    from open_simulator_tpu.cli.main import main
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "xla"))
+    calls = []
+    real_run = subprocess.run
+
+    def spy(cmd, *a, **k):
+        calls.append(list(cmd))
+        return real_run(cmd, *a, **k)
+
+    monkeypatch.setattr(subprocess, "run", spy)
+    cfg = os.path.join(
+        os.path.dirname(__file__), "fixtures", "sweep", "simon-config.yaml"
+    )
+    plan = os.path.join(
+        os.path.dirname(__file__), "..", "example", "fault-plan-device.yaml"
+    )
+    rc = main(["chaos", "--capacity", "-f", cfg, "--fault-plan", plan,
+               "--run-dir", str(tmp_path / "run")])
+    out = capsys.readouterr().out
+    assert rc == 0, out
+    assert "killed mid-plan (SIGKILL), resumed from checkpoint" in out
+    assert "placement digest vs clean reference: match" in out
+    sweeps = [c for c in calls if "sweep" in c]
+    assert len(sweeps) == 3 and "--resume" in sweeps[2]

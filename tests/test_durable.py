@@ -16,6 +16,7 @@ import threading
 import pytest
 
 from open_simulator_tpu.durable import (
+    BackendUnavailable,
     DeadlineExceeded,
     RunJournal,
     acquire_backend,
@@ -142,17 +143,15 @@ def test_summarize_and_list_runs(tmp_path):
     b = str(tmp_path / "b")
     with RunJournal.open(b) as j:
         j.append("run_start", kind="bench")
-        j.append(
-            "backend_fallback", fallback="cpu", fallback_reason="timed out"
-        )
+        j.append("backend_error", platform="tpu", error="timed out")
     sa = summarize_run(a)
     assert sa["kind"] == "apply" and sa["status"] == "completed"
     assert sa["outcome"] == "ok" and sa["trials"] == 1
-    assert sa["device"] == "TFRT_CPU_0" and sa["fallback"] == ""
+    assert sa["device"] == "TFRT_CPU_0" and sa["backend_error"] == ""
     sb = summarize_run(b)
     assert sb["status"] == "in-flight/crashed"
-    # no probed device name, but the fallback still names the backend
-    assert sb["device"] == "cpu" and sb["fallback"] == "cpu"
+    # no device was acquired; the error is what the summary shows
+    assert sb["device"] == "" and sb["backend_error"] == "timed out"
     rows = list_runs(str(tmp_path))
     assert [r["name"] for r in rows] == ["b", "a"]  # newest first
 
@@ -215,34 +214,58 @@ def test_acquire_backend_happy_path(tmp_path):
     journal.close()
 
 
-def test_acquire_backend_degrades_to_cpu(tmp_path, monkeypatch):
-    # conftest pins JAX_PLATFORMS=cpu, so the "fallback" lands on the same
-    # backend — what matters is the honest labeling and the journal trail
-    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+def test_acquire_backend_failure_raises_without_switching(tmp_path):
+    """A failing probe is journaled and raised, naming the platform; the
+    process stays on the platform it asked for."""
+    import jax
+
     calls = []
 
     def bad_probe():
         calls.append(1)
-        raise RuntimeError("tunnel wedged")
+        raise RuntimeError("device init failed")
 
+    before = os.environ.get("JAX_PLATFORMS")
     journal = RunJournal.open(str(tmp_path / "run"))
-    info = acquire_backend(
-        deadline_s=60, journal=journal, probe=bad_probe, poll_s=0.001
-    )
-    assert len(calls) == 2  # first try + one cache-warmed retry
-    assert info["fallback"] == "cpu"
-    assert "tunnel wedged" in info["fallback_reason"]
-    assert info["device"]  # a real CPU device string, never empty
-    assert [e["event"] for e in journal.events()] == [
-        "backend_retry", "backend_fallback",
-    ]
-    assert journal.events("backend_fallback")[0]["fallback"] == "cpu"
+    with pytest.raises(BackendUnavailable) as exc:
+        acquire_backend(
+            deadline_s=60, journal=journal, probe=bad_probe, poll_s=0.001
+        )
+    assert len(calls) == 1
+    assert exc.value.platform == "cpu"
+    assert "'cpu'" in str(exc.value) and "device init failed" in str(exc.value)
+    assert [e["event"] for e in journal.events()] == ["backend_error"]
+    err = journal.events("backend_error")[0]
+    assert err["platform"] == "cpu" and "device init failed" in err["error"]
+    assert os.environ.get("JAX_PLATFORMS") == before
+    assert jax.config.jax_platforms == "cpu"
     journal.close()
 
 
-def test_backend_fault_injection_trips_ladder(tmp_path, monkeypatch):
+def test_apply_with_a_hung_backend_exits_naming_the_platform(
+    tmp_path, monkeypatch, capsys
+):
+    """`simon apply` against a backend whose init outlives the deadline
+    exits 1 with the platform in the error; it never runs elsewhere."""
+    from open_simulator_tpu.cli.main import main
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "xla"))
+    monkeypatch.setenv("OSIM_BACKEND_DEADLINE_S", "0.2")
+    monkeypatch.setenv(
+        "OSIM_FAULT_PLAN",
+        "rules:\n  - target: backend\n    op: acquire\n"
+        "    kind: latency\n    latency_s: 2\n",
+    )
+    rc = main(["apply", "-f", CONFIG, "--run-dir", str(tmp_path / "run")])
+    assert rc == 1
+    assert "backend 'cpu' unavailable" in capsys.readouterr().err
+    events = [e["event"] for e in replay(str(tmp_path / "run"))]
+    assert "watchdog" in events and "backend_error" in events
+    assert "run_end" not in events
+
+
+def test_backend_fault_injection_fails_acquisition(tmp_path):
     # OSIM_FAULT_PLAN-style plan against the backend-acquire injection point
-    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
     plan = faults.FaultPlan.from_dict({
         "rules": [{"target": "backend", "op": "acquire", "kind": "error"}],
     })
@@ -250,12 +273,12 @@ def test_backend_fault_injection_trips_ladder(tmp_path, monkeypatch):
 
     with faults.injected(plan):
         journal = RunJournal.open(str(tmp_path / "run"))
-        info = acquire_backend(
-            deadline_s=60, journal=journal, probe=_default_probe,
-            poll_s=0.001,
-        )
-    assert info["fallback"] == "cpu"
-    assert "injected by fault plan" in info["fallback_reason"]
+        with pytest.raises(BackendUnavailable, match="injected by fault plan"):
+            acquire_backend(
+                deadline_s=60, journal=journal, probe=_default_probe,
+                poll_s=0.001,
+            )
+    assert [e["event"] for e in journal.events()] == ["backend_error"]
     journal.close()
 
 
@@ -617,10 +640,9 @@ def test_run_apply_journals_and_resumes_identically(tmp_path):
     out = io.StringIO()
     outcome = run_apply(cfg, out=out, run_dir=d, config_path=CONFIG)
     assert outcome.device  # provenance always stamped
-    assert outcome.fallback == ""  # honest: no fallback happened
     first = open(os.path.join(d, "outcome.json"), "rb").read()
     doc = json.loads(first)
-    for key in ("device", "fallback", "fallback_reason", "placement_digest"):
+    for key in ("device", "placement_digest"):
         assert key in doc  # TOP-LEVEL provenance fields
     events = [e["event"] for e in replay(d)]
     assert events[0] == "run_start" and "run_end" in events

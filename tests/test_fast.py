@@ -722,14 +722,13 @@ def test_spread_missing_key_nodes(spread_path):
 
 
 @pytest.mark.parametrize("hard", [True, False])
-def test_domain_pallas_kernel_parity(monkeypatch, hard):
-    """OSIM_PALLAS=1 routes the domain pop loop through the fused Pallas
-    kernel (interpret mode on CPU) — placements, reasons, takes and carry
-    must stay exactly oracle-identical, for both kernel variants (with and
-    without the DoNotSchedule hard-mask branch)."""
+def test_domain_path_parity_tight_zones(hard):
+    """Small tight zones under soft (and optionally DoNotSchedule) zone
+    spread take the domain-merge path, and its placements, reasons, takes
+    and carry stay exactly oracle-identical, with and without the hard-mask
+    branch."""
     from open_simulator_tpu.ops import fast
 
-    monkeypatch.setenv("OSIM_PALLAS", "1")
     nodes = [
         _node(
             f"n-{i}", cpu="4" if i < 3 else "32", pods="12",
@@ -761,9 +760,8 @@ def test_domain_pallas_kernel_parity(monkeypatch, hard):
     ns, carry, batch = _encode(nodes, [tmpl], [120])
     before = dict(fast.PATH_COUNTS)
     _assert_identical(ns, carry, batch)
-    # domain_pallas proves the kernel (not the XLA scan) actually produced
-    # the parity-checked result
-    assert fast.PATH_COUNTS["domain_pallas"] > before["domain_pallas"]
+    # the domain path (not the micro scan) produced the checked result
+    assert fast.PATH_COUNTS["domain"] > before["domain"]
 
 
 def test_spread_with_host_ports(spread_path):

@@ -7,7 +7,7 @@ demands `schedule_batch_fast` reproduce the sequential oracle EXACTLY
 (placements, reasons, takes, final carry via `_assert_identical`).
 
 The same generator ran as a long soak during development (hundreds of
-rounds across seeds, including OSIM_PALLAS=1); this bounded version keeps a
+rounds across seeds); this bounded version keeps a
 few representative seeds in CI so path-dispatch regressions can't land
 silently.
 """

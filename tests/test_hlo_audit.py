@@ -360,15 +360,43 @@ def test_budget_write_and_diff_flow(caps, tmp_path):
 
 
 def test_plan_verdict_fits(caps, tables):
-    v = H.plan_verdict(caps, hbm_gib=32.0, tables=tables)
+    # on the CPU the budget is the target chip's: one v5e, 16 GB
+    v = H.plan_verdict(caps, tables=tables)
     assert v["config"] == "plan_1m_100k"
     assert v["mesh"] == "1x4"
     assert v["rung"] == 102400
+    assert v["hbm_gib"] == round(16e9 / 1024 ** 3, 3)
     assert not v.get("error"), v
     assert v["fits"] is True
     assert v["node_table_sharded"] is True
-    assert v["peak_gib"] < 32.0
+    assert v["peak_gib"] < v["hbm_gib"]
     assert v["ok"] is True
+
+
+class _FakeDevice:
+    def __init__(self, platform, kind, stats):
+        self.platform, self.device_kind, self._stats = platform, kind, stats
+
+    def memory_stats(self):
+        return self._stats
+
+
+@pytest.mark.parametrize(
+    "device, want",
+    [
+        (None, 16 * 10 ** 9),  # this process's CPU -> the target chip
+        (_FakeDevice("tpu", "TPU v5 lite", {"bytes_limit": 123}), 123),
+        (_FakeDevice("tpu", "TPU v5 lite", None), 16 * 10 ** 9),
+        (_FakeDevice("tpu", "TPU v99", {}), KeyError),
+    ],
+    ids=["cpu-target", "chip-bytes-limit", "chip-table", "unknown-kind"],
+)
+def test_device_hbm_bytes(device, want):
+    if want is KeyError:
+        with pytest.raises(KeyError, match="TPU v99"):
+            H.device_hbm_bytes(device)
+    else:
+        assert H.device_hbm_bytes(device) == want
 
 
 def test_plan_verdict_without_entry_reports_error():

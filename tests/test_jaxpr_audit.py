@@ -71,7 +71,7 @@ def test_audit_flags_wide_avals():
     import jax
     import jax.numpy as jnp
 
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
 
         def wide(x):
             return x.astype(jnp.float64) * 2.0

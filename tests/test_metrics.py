@@ -308,9 +308,8 @@ def test_metrics_file_cli_flag(tmp_path, monkeypatch):
 
     from open_simulator_tpu.cli.main import main
 
-    # keep the CLI entry point from flipping the persistent compilation
-    # cache on for the rest of the suite (see test_bench.py)
-    monkeypatch.setenv("OSIM_COMPILE_CACHE", "")
+    # keep the CLI entry point's compilation cache out of the checkout
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "xla"))
 
     cfg_dir = tmp_path / "cluster"
     cfg_dir.mkdir()

@@ -140,3 +140,15 @@ def test_group_runs_use_native_hashing():
     enc.register_pods(pods)
     batch = encode_pods(enc, pods)
     assert group_runs(batch) == [(0, 5), (5, 3)]
+
+
+def test_library_name_tracks_source(tmp_path, monkeypatch):
+    """A library built from another version of the source is never
+    loaded: its file name carries the source's hash."""
+    src = tmp_path / "osim_native.cpp"
+    with open(native._SRC, "rb") as fh:
+        src.write_bytes(fh.read())
+    monkeypatch.setattr(native, "_SRC", str(src))
+    before = native._so_path()
+    src.write_bytes(src.read_bytes() + b"\n// edited\n")
+    assert native._so_path() != before

@@ -741,7 +741,7 @@ def test_chaos_report_deterministic_and_degraded(tmp_path, capsys, monkeypatch):
     reports across two runs; an injected chart fault degrades (exit 0)."""
     from open_simulator_tpu.cli.main import main
 
-    monkeypatch.setenv("OSIM_COMPILE_CACHE", "")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "xla"))
     cfg_path, plan_path = _chaos_fixture(tmp_path)
     argv = ["chaos", "-f", str(cfg_path), "--fault-plan", str(plan_path)]
 
