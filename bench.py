@@ -2123,26 +2123,6 @@ def _segment_main(name: str, pods: int, nodes: int) -> int:
 
         # every segment names the device it ran on
         out.setdefault("device", str(jax.devices()[0]))
-        # device-time evidence (utils/profiling.py): always present so JSON
-        # consumers can key on the fields; null unless OSIM_DEVICE_PROFILE=1
-        # opts the segment into the post-run dispatch-gap analysis (the
-        # sandwich re-times every audited entry, so it is not free).
-        out.setdefault("device_time_ms", None)
-        out.setdefault("dispatch_gap_ratio", None)
-        if os.environ.get("OSIM_DEVICE_PROFILE", "") == "1":
-            try:
-                from open_simulator_tpu.utils.profiling import (
-                    analyze_dispatch_gaps,
-                )
-
-                rep = analyze_dispatch_gaps(repeats=1)
-                out["device_time_ms"] = rep.device_time_ms
-                out["dispatch_gap_ratio"] = rep.dispatch_gap_ratio
-                out["device_profile"] = rep.to_dict()
-            except Exception as e:  # noqa: BLE001 - profiling must not fail the segment
-                out["device_profile"] = {
-                    "error": f"{type(e).__name__}: {e}"
-                }
     print(json.dumps(out), flush=True)
     return 0
 

@@ -14,9 +14,10 @@ loop whose body also calls a jit entry point. ``np.asarray``/``float``/
 entry's result — coercing genuine numpy state in the same loop is host
 arithmetic, not a sync. A consolidated ``jax.device_get`` of many results
 at once is the blessed idiom this rule pushes toward and is deliberately
-NOT flagged. Syncs outside such loops (epilogues, one-shot reads after a
-batch) are fine; traced code is the purity rules' business and is
-excluded here. A deliberate per-iteration sync (e.g. a small mask the
+NOT flagged; so is the tracing layer's ``fetch(tree, site)``, which is one
+``jax.device_get`` inside a ``host-sync`` span. Syncs outside such loops
+(epilogues, one-shot reads after a batch) are fine; traced code is the
+purity rules' business and is excluded here. A deliberate per-iteration sync (e.g. a small mask the
 host algorithm genuinely needs before the next dispatch) takes the
 standard ``osim: lint-ok[device-sync-in-loop]`` comment escape with a
 one-line justification.
@@ -39,6 +40,19 @@ _NP_PULLS = {"asarray", "array"}
 _COERCERS = {"float", "int"}
 
 
+def _is_fetch(ctx: LintContext, mod: ModuleInfo, func: ast.expr) -> bool:
+    """``jax.device_get(...)``, or ``fetch(...)`` of a module named
+    ``tracing`` (utils/tracing.fetch: one device_get in a host-sync span)."""
+    if isinstance(func, ast.Attribute) and func.attr == "device_get":
+        return True
+    resolved = ctx.resolve_call(mod, func)
+    return (
+        resolved is not None
+        and resolved[1] == "fetch"
+        and resolved[0].rsplit(".", 1)[-1] == "tracing"
+    )
+
+
 def _is_jitish(
     ctx: LintContext, mod: ModuleInfo, func: ast.expr,
     cache: Dict[Tuple[str, str], bool],
@@ -46,9 +60,9 @@ def _is_jitish(
     """True when ``func`` resolves to a jit entry, or to a thin wrapper
     whose body calls one directly (``ops.grouped._group_call``-style
     dispatchers return device arrays just like the entry itself). A
-    wrapper that itself calls ``jax.device_get`` is host-returning
-    (``schedule_scenarios_host``-style drivers do the one consolidated
-    fetch internally) and is NOT jit-ish."""
+    wrapper that itself calls ``jax.device_get`` or the tracing ``fetch``
+    is host-returning (``schedule_scenarios_host``-style drivers do the one
+    consolidated fetch internally) and is NOT jit-ish."""
     resolved = ctx.resolve_call(mod, func)
     if resolved is None:
         return False
@@ -67,7 +81,7 @@ def _is_jitish(
                 if not isinstance(node, ast.Call):
                     continue
                 f = node.func
-                if isinstance(f, ast.Attribute) and f.attr == "device_get":
+                if _is_fetch(ctx, tmod, f):
                     fetches = True
                     break
                 inner = ctx.resolve_call(tmod, f)
@@ -78,6 +92,21 @@ def _is_jitish(
             result = calls_jit and not fetches
     cache[resolved] = result
     return result
+
+
+def _calls_outside_fetch(
+    ctx: LintContext, mod: ModuleInfo, expr: ast.expr
+) -> Iterator[ast.Call]:
+    """Calls in ``expr``, not descending into a fetch: what a fetch
+    returns is host data, whatever it was computed from."""
+    stack: List[ast.AST] = [expr]
+    while stack:
+        node = stack.pop()
+        if isinstance(node, ast.Call):
+            if _is_fetch(ctx, mod, node.func):
+                continue
+            yield node
+        stack.extend(ast.iter_child_nodes(node))
 
 
 def _device_names(
@@ -91,9 +120,8 @@ def _device_names(
         if not isinstance(node, ast.Assign):
             continue
         produces_device = any(
-            isinstance(sub, ast.Call)
-            and _is_jitish(ctx, mod, sub.func, jitish_cache)
-            for sub in ast.walk(node.value)
+            _is_jitish(ctx, mod, sub.func, jitish_cache)
+            for sub in _calls_outside_fetch(ctx, mod, node.value)
         )
         if not produces_device:
             continue

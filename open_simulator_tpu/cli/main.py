@@ -1380,37 +1380,20 @@ def _run_warmup(args) -> int:
 def _add_profile(sub: argparse._SubParsersAction) -> None:
     p = sub.add_parser(
         "profile",
-        help="capture a device trace and/or run the dispatch-gap analyzer",
+        help="run a simon command under a jax.profiler device trace",
         description=(
-            "Device-time profiling (docs/observability.md). With a trailing "
-            "simon command (`simon profile -- apply -f cfg.yaml`), run it "
-            "under a jax.profiler device trace written to --out "
-            "(Perfetto/TensorBoard-loadable). Without a command — or with "
-            "--gaps — time every audited jit entry at its canonical shapes "
-            "with the block_until_ready sandwich and report per-entry "
-            "device time plus the dispatch-gap ratio (the fraction of wall "
-            "time the device sat idle waiting for the host), published as "
-            "osim_device_time_seconds / osim_dispatch_gap_ratio."
+            "Device-time profiling (docs/observability.md): run a simon "
+            "command (`simon profile -- apply -f cfg.yaml`) under a "
+            "jax.profiler device trace written to --out "
+            "(Perfetto/TensorBoard-loadable). Every program span appears on "
+            "the host timeline, so an idle stretch of the device is named "
+            "by the span the host was in."
         ),
     )
     p.add_argument(
         "--out", default="",
         help="device-trace output directory (default: "
         "<runs root>/device-profile)",
-    )
-    p.add_argument(
-        "--gaps", action="store_true",
-        help="also run the dispatch-gap analyzer after the traced command "
-        "(implied when no command is given)",
-    )
-    p.add_argument(
-        "--entries", default="",
-        help="comma-separated audit entry names to analyze (default: every "
-        "registry entry; names as in `simon audit`)",
-    )
-    p.add_argument(
-        "--repeats", type=int, default=3,
-        help="timed repeats per entry, keeping the fastest (default 3)",
     )
     p.add_argument(
         "--format", choices=("text", "json"), default="text",
@@ -1427,42 +1410,24 @@ def _run_profile(args) -> int:
     import json as _json
 
     from ..durable import default_runs_root
-    from ..utils.profiling import analyze_dispatch_gaps, capture_device_trace
+    from ..utils.profiling import capture_device_trace
 
     cmd = [c for c in args.cmd if c != "--"]
-    report: dict = {}
-    rc = 0
-    if cmd:
-        out_dir = args.out or os.path.join(
-            default_runs_root(), "device-profile"
-        )
-        rc_box: list = []
-        report["trace"] = capture_device_trace(
-            out_dir, fn=lambda: rc_box.append(main(cmd))
-        )
-        rc = rc_box[0] if rc_box else 1
-        if not report["trace"].get("ok"):
-            rc = rc or 1
-    gaps = None
-    if args.gaps or not cmd:
-        names = [
-            s.strip() for s in args.entries.split(",") if s.strip()
-        ] or None
-        try:
-            gaps = analyze_dispatch_gaps(names=names, repeats=args.repeats)
-        except KeyError as e:
-            print(f"error: unknown audit entry {e}", file=sys.stderr)
-            return 1
-        report["dispatch_gaps"] = gaps.to_dict()
+    if not cmd:
+        print("error: simon profile needs a command to trace, e.g. "
+              "`simon profile -- apply -f cfg.yaml`", file=sys.stderr)
+        return 2
+    out_dir = args.out or os.path.join(default_runs_root(), "device-profile")
+    rc_box: list = []
+    trace = capture_device_trace(out_dir, fn=lambda: rc_box.append(main(cmd)))
+    rc = rc_box[0] if rc_box else 1
+    if not trace.get("ok"):
+        rc = rc or 1
     if args.format == "json":
-        print(_json.dumps(report, indent=2, sort_keys=True))
+        print(_json.dumps({"trace": trace}, indent=2, sort_keys=True))
     else:
-        if "trace" in report:
-            t = report["trace"]
-            state = "ok" if t.get("ok") else f"failed: {t.get('error')}"
-            print(f"device trace ({state}): {t.get('trace_dir')}")
-        if gaps is not None:
-            print(gaps.render_text())
+        state = "ok" if trace.get("ok") else f"failed: {trace.get('error')}"
+        print(f"device trace ({state}): {trace.get('trace_dir')}")
     return rc
 
 

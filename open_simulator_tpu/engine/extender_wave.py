@@ -47,7 +47,7 @@ from ..ops.kernels import commit_wave, probe_many
 from ..resilience import faults
 from ..utils import metrics
 from ..utils.httppool import configured_pool_size
-from ..utils.tracing import activate, current_context, log, span
+from ..utils.tracing import activate, current_context, fetch, log, span
 from .extenders import (
     EXTENDER_SCORE_SCALE,
     ExtenderError,
@@ -235,7 +235,7 @@ def run_waves(
                 sim._ns, sim._carry, wave_rows, weights, fo,
                 sim._extra_filters, sim._extra_scores,
             )
-            mask_np, ff_np = jax.device_get((mask, ff))
+            mask_np, ff_np = fetch((mask, ff), "extender-wave-probe")
             metrics.EXTENDER_WAVE_SIZE.observe(len(idx))
             futures = []
             for w, i in enumerate(idx):
@@ -317,8 +317,9 @@ def run_waves(
                 # cur's commit is in flight on device: drain nxt's HTTP and
                 # build its glue NOW, where both hide behind device time
                 prepare(nxt)
-            nodes_np, respill_np, take_np, vg_np, dev_np = jax.device_get(
-                (nodes, respill, gpu_take, vg_take, dev_take)
+            nodes_np, respill_np, take_np, vg_np, dev_np = fetch(
+                (nodes, respill, gpu_take, vg_take, dev_take),
+                "extender-wave-commit",
             )
 
             nz = np.flatnonzero(respill_np[:w_real])

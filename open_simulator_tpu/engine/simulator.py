@@ -66,7 +66,7 @@ from ..ops.state import (
     stack_carry,
 )
 from ..utils import metrics
-from ..utils.tracing import progress, span
+from ..utils.tracing import fetch, progress, span
 
 
 @dataclass
@@ -297,6 +297,16 @@ class Simulator:
         node axis must equal `n_pad`. Pods are still registered on the
         shared encoder — registration is content-keyed and idempotent, so
         re-registering the same workload is free and never shifts ids."""
+        with span("prepare-cluster", pods=len(cluster.pods)):
+            self._prepare(
+                cluster, weights, use_greed, mesh, n_pad, profiles, plugins,
+                patch_pods, expand_cache, extenders, resident, preencoded,
+            )
+
+    def _prepare(
+        self, cluster, weights, use_greed, mesh, n_pad, profiles, plugins,
+        patch_pods, expand_cache, extenders, resident, preencoded,
+    ) -> None:
         self.cluster = cluster
         self.use_greed = use_greed
         self.mesh = mesh
@@ -574,16 +584,18 @@ class Simulator:
     ) -> List[UnscheduledPod]:
         with span("encode", pods=len(pods)):
             batch = encode_pods(self.enc, pods)
-        carry0, ns0 = self._carry, self._ns
-        self._carry, self._ns = align_carry(self._carry, self.enc, self._ns)
-        if self._carry is not carry0 or self._ns is not ns0:
-            self._reshard()
         # Fast path: identical results to the naive scan — static work hoisted
         # per run of identical pods, big runs via per-node trajectories + the
         # light selection scan (ops/fast.py).
         import jax.numpy as jnp
 
         with span("schedule", pods=len(pods)) as sp:
+            carry0, ns0 = self._carry, self._ns
+            self._carry, self._ns = align_carry(
+                self._carry, self.enc, self._ns
+            )
+            if self._carry is not carry0 or self._ns is not ns0:
+                self._reshard()
             (
                 self._carry,
                 placed_np,
@@ -606,17 +618,18 @@ class Simulator:
         metrics.SCHEDULING_ATTEMPTS.inc(len(pods))
         failed: List[UnscheduledPod] = []
         n_nodes = len(self.cluster.nodes)
-        fail_counts = np.zeros(reasons_np.shape[1], np.int64)
-        for i, pod in enumerate(pods):
-            ni = int(placed_np[i])
-            if ni >= 0:
-                self._bind_placed(pod, ni, take_np[i], vg_np[i], dev_np[i])
-            else:
-                fail_counts += reasons_np[i]
-                failed.append(
-                    UnscheduledPod(pod, _reason_string(n_nodes, reasons_np[i]))
-                )
-        _count_filter_failures(fail_counts)
+        with span("bind-placements", pods=len(pods)):
+            fail_counts = np.zeros(reasons_np.shape[1], np.int64)
+            for i, pod in enumerate(pods):
+                ni = int(placed_np[i])
+                if ni >= 0:
+                    self._bind_placed(pod, ni, take_np[i], vg_np[i], dev_np[i])
+                else:
+                    fail_counts += reasons_np[i]
+                    failed.append(UnscheduledPod(
+                        pod, _reason_string(n_nodes, reasons_np[i])
+                    ))
+            _count_filter_failures(fail_counts)
         return failed
 
     def _bind_placed(self, pod: Pod, ni: int, take_row, vg_row, dev_row) -> None:
@@ -708,8 +721,8 @@ class Simulator:
                     self._ns, self._carry, row, weights, fo,
                     self._extra_filters, self._extra_scores,
                 )
-                mask_np, score_np, ff_np = jax.device_get(
-                    (mask, score, first_fail)
+                mask_np, score_np, ff_np = fetch(
+                    (mask, score, first_fail), "extender-probe"
                 )
                 feasible = [
                     self.cluster.nodes[j] for j in range(n_nodes) if mask_np[j]
@@ -782,8 +795,8 @@ class Simulator:
                 self._carry, take, vg_take, dev_take = commit_step(
                     self._ns, self._carry, row, jnp.int32(best_ni)
                 )
-                take_np, vg_np, dev_np = jax.device_get(
-                    (take, vg_take, dev_take)
+                take_np, vg_np, dev_np = fetch(
+                    (take, vg_take, dev_take), "extender-commit"
                 )
                 self._bind_placed(pod, best_ni, take_np, vg_np, dev_np)
                 scheduled += 1
@@ -935,9 +948,10 @@ class Simulator:
         numpy slice, not an un-jitted device get."""
         cached = getattr(self, "_carry_np", None)
         if cached is None or cached[0] is not self._carry:
+            host = fetch(self._carry, "probe-carry")
             planes = []
             for key, field_name, node_major in self._PROBE_PLANES:
-                a = np.asarray(getattr(self._carry, field_name), np.float32)
+                a = np.asarray(getattr(host, field_name), np.float32)
                 planes.append(a.T if node_major else a)
             self._carry_np = (self._carry, np.concatenate(planes, axis=0))
         return self._carry_np[1]
@@ -1090,7 +1104,9 @@ class Simulator:
                     self._ns, self._carry, row, jnp.asarray(nis),
                     jnp.asarray(stacked), fo,
                 )
-                out.extend(bool(b) for b in np.asarray(res)[:c])
+                out.extend(
+                    bool(b) for b in fetch(res, "preempt-probe")[:c]
+                )
             return out
 
         return fits_many
@@ -1103,6 +1119,14 @@ class Simulator:
         Successful preemptors are rescheduled immediately; victims are
         removed from the cluster (the reference deletes them,
         default_preemption.go PrepareCandidate)."""
+        if not any(u.pod.priority > 0 for u in failed):
+            return failed
+        with span("preemption", pods=len(failed)):
+            return self._preempt_failed(failed)
+
+    def _preempt_failed(
+        self, failed: List[UnscheduledPod]
+    ) -> List[UnscheduledPod]:
         from .extenders import ExtenderError
         from .preemption import try_preempt
 
@@ -1180,15 +1204,16 @@ class Simulator:
             (p, n) for p, n in self._bound if id(p) not in victim_keys
         ]
         ni = self._table.names.index(node_name)
-        free = np.asarray(self._carry.free).copy()
-        sel = np.asarray(self._carry.sel_counts).copy()
-        gpu = np.asarray(self._carry.gpu_free).copy()
-        vg = np.asarray(self._carry.vg_free).copy()
-        dev = np.asarray(self._carry.dev_free).copy()
-        port_any = np.asarray(self._carry.port_any).copy()
-        port_wild = np.asarray(self._carry.port_wild).copy()
-        port_ipc = np.asarray(self._carry.port_ipc).copy()
-        anti = np.asarray(self._carry.anti_counts).copy()
+        host = fetch(self._carry, "evict")
+        free = np.array(host.free)
+        sel = np.array(host.sel_counts)
+        gpu = np.array(host.gpu_free)
+        vg = np.array(host.vg_free)
+        dev = np.array(host.dev_free)
+        port_any = np.array(host.port_any)
+        port_wild = np.array(host.port_wild)
+        port_ipc = np.array(host.port_ipc)
+        anti = np.array(host.anti_counts)
         from ..ops.encode import resource_scale
 
         from ..ops.encode import match_vector
@@ -1332,44 +1357,50 @@ class Simulator:
 
     # -- public ------------------------------------------------------------
     def run(self, apps: Sequence[AppResource]) -> SimulateResult:
+        """One simulation under its own `simulate` root span (simulate()
+        opens the root before construction instead, so that it also covers
+        `prepare-cluster`)."""
         with span("simulate", nodes=len(self.cluster.nodes), apps=len(apps)):
-            app_pods = self._expand_apps(apps)
+            return self._run(apps)
 
-            with span("encode-cluster"):
-                self._build_device_state(
-                    self._pending_cluster + [p for pods in app_pods for p in pods]
-                )
+    def _run(self, apps: Sequence[AppResource]) -> SimulateResult:
+        app_pods = self._expand_apps(apps)
 
-            result = SimulateResult()
-            # RunCluster: the cluster's own pending pods schedule first.
-            result.unscheduled.extend(
-                self._finalize_unscheduled(
-                    self._try_preemptions(
-                        self._schedule_batch_host(
-                            self._order(self._pending_cluster)
-                        )
+        with span("encode-cluster"):
+            self._build_device_state(
+                self._pending_cluster + [p for pods in app_pods for p in pods]
+            )
+
+        result = SimulateResult()
+        # RunCluster: the cluster's own pending pods schedule first.
+        result.unscheduled.extend(
+            self._finalize_unscheduled(
+                self._try_preemptions(
+                    self._schedule_batch_host(
+                        self._order(self._pending_cluster)
                     )
                 )
             )
-            # ScheduleApp: each app in configured order.
-            for pods in app_pods:
-                result.unscheduled.extend(
-                    self._finalize_unscheduled(
-                        self._try_preemptions(self._schedule_batch_host(pods))
-                    )
+        )
+        # ScheduleApp: each app in configured order.
+        for pods in app_pods:
+            result.unscheduled.extend(
+                self._finalize_unscheduled(
+                    self._try_preemptions(self._schedule_batch_host(pods))
                 )
+            )
 
-            with span("decode-result"):
-                by_node: Dict[str, NodeStatus] = {
-                    n.name: NodeStatus(node=n) for n in self.cluster.nodes
-                }
-                for pod, node_name in self._bound:
-                    if node_name in by_node:
-                        by_node[node_name].pods.append(pod)
-                result.node_status = list(by_node.values())
-                result.storage = self._storage_status()
-                result.preempted = list(self._preempted)
-            return result
+        with span("decode-result"):
+            by_node: Dict[str, NodeStatus] = {
+                n.name: NodeStatus(node=n) for n in self.cluster.nodes
+            }
+            for pod, node_name in self._bound:
+                if node_name in by_node:
+                    by_node[node_name].pods.append(pod)
+            result.node_status = list(by_node.values())
+            result.storage = self._storage_status()
+            result.preempted = list(self._preempted)
+        return result
 
     def run_scenarios(
         self,
@@ -1607,10 +1638,8 @@ class Simulator:
                                         n_nodes_s[s], reasons_np[s, p_idx]
                                     ),
                                 ))
-            import jax
-
-            vg_free_s, dev_free_s = jax.device_get(
-                (carry_s.vg_free, carry_s.dev_free)
+            vg_free_s, dev_free_s = fetch(
+                (carry_s.vg_free, carry_s.dev_free), "scenario-storage"
             )
             vg_free_s = np.asarray(vg_free_s)
             dev_free_s = np.asarray(dev_free_s)
@@ -1703,8 +1732,9 @@ class Simulator:
         if vg_free is None or dev_free is None:
             if self._carry is None:
                 return out
-            vg_free = np.asarray(self._carry.vg_free)
-            dev_free = np.asarray(self._carry.dev_free)
+            vg_free, dev_free = fetch(
+                (self._carry.vg_free, self._carry.dev_free), "storage"
+            )
         for i, node in enumerate(self.cluster.nodes):
             if keep is not None and not keep[i]:
                 continue
@@ -1764,11 +1794,15 @@ def simulate(
     filter/prioritize callbacks (WithExtenders parity).
     `resident`: optional engine/resident.ResidentCluster serving fast path
     (see Simulator)."""
-    return Simulator(
-        cluster, weights=weights, use_greed=use_greed, mesh=mesh, n_pad=n_pad,
-        profiles=profiles, plugins=plugins, patch_pods=patch_pods,
-        expand_cache=expand_cache, extenders=extenders, resident=resident,
-    ).run(apps)
+    # one root per call, opened before construction: the copies of the
+    # cluster's pods (`prepare-cluster`) are part of the call
+    with span("simulate", nodes=len(cluster.nodes), apps=len(apps)):
+        return Simulator(
+            cluster, weights=weights, use_greed=use_greed, mesh=mesh,
+            n_pad=n_pad, profiles=profiles, plugins=plugins,
+            patch_pods=patch_pods, expand_cache=expand_cache,
+            extenders=extenders, resident=resident,
+        )._run(apps)
 
 
 def batch_ineligible_reason(

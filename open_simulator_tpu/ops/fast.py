@@ -83,6 +83,7 @@ from . import delta as _delta
 from . import wave as _wave
 from .state import pod_rows_from_batch
 from ..utils import metrics as _metrics
+from ..utils.tracing import fetch
 
 # Trajectories longer than this fall back to the per-pod grouped path (a node
 # that can absorb >512 copies of one pod implies an unrealistically small
@@ -1415,21 +1416,22 @@ def schedule_batch_fast(
     dev_out = np.zeros((P, DV), np.float32)
     rows_all = pod_rows_from_batch(batch)
     N = ns.valid.shape[0]
-    valid_np = np.asarray(ns.valid)
-
+    # One device->host sync for everything the host plans from: the node
+    # table's valid/topology planes, the filter mask, and the batch-entry
+    # free for ALL groups' trajectory lengths (free only shrinks while a
+    # batch schedules — no evictions mid-batch — so per-node commit caps
+    # computed from it are safe upper bounds for every later group).
+    valid_np, anti_topo_np, topo_np, filter_np, free_np = fetch(
+        (ns.valid, ns.anti_topo, ns.topo, filter_on, carry.free),
+        "batch-entry",
+    )
     # A disabled NodeResourcesFit filter voids the trajectory-length bound
     # (the resource filter is what stops a node's commit count at c_max, see
     # _traj_len) — those profiles take the per-pod grouped path.
     res_filter_on = (
-        filter_on is None or bool(np.asarray(filter_on)[F_RESOURCES])
+        filter_np is None or bool(filter_np[F_RESOURCES])
     ) and not extra_filters and not extra_scores
-    # One device->host sync for ALL groups' trajectory lengths: free only
-    # shrinks while a batch schedules (no evictions mid-batch), so per-node
-    # commit caps computed from the batch-entry free are safe upper bounds
-    # for every later group.
-    free_entry = np.asarray(carry.free) if res_filter_on else None
-    anti_topo_np = np.asarray(ns.anti_topo)
-    topo_np = np.asarray(ns.topo)
+    free_entry = free_np if res_filter_on else None
     n_domains = int(ns.topo_onehot.shape[1])
 
     for start, length in group_runs(batch):
@@ -1473,8 +1475,8 @@ def schedule_batch_fast(
                     extra_filters, extra_scores,
                 )
                 sl = slice(start + done, start + done + n)
-                nodes_np, reasons_np, take_np, vg_np, dev_np = jax.device_get(
-                    (nodes, reasons, take, vg_take, dev_take)
+                nodes_np, reasons_np, take_np, vg_np, dev_np = fetch(
+                    (nodes, reasons, take, vg_take, dev_take), "grouped-chunk"
                 )
                 nodes_out[sl] = nodes_np[:n]
                 reasons_out[sl] = reasons_np[:n]
@@ -1506,9 +1508,10 @@ def schedule_batch_fast(
             )
             carry_dev = exit_carry(ns, carry, row, traj, x_dev)
             _progress(f"group@{start} finish sync")
-            mono_np, *got = jax.device_get(
+            mono_np, *got = fetch(
                 (jnp.bool_(True) if mono_dev is None else mono_dev,
-                 nodes_dev, take_dev, vg_dev, dev_dev, reason_dev)
+                 nodes_dev, take_dev, vg_dev, dev_dev, reason_dev),
+                "group-finish",
             )
             return bool(mono_np), tuple(got), carry_dev
 
@@ -1553,8 +1556,7 @@ def schedule_batch_fast(
             # Domain-merge path: O(Dc) scan state instead of O(N). The class
             # partition needs the pod's spread eligibility on host (one small
             # bool[N] transfer per group).
-            # deliberate bool[N] fetch: the domain partition is planned on host
-            elig_np = np.asarray(na_ok) & valid_np  # osim: lint-ok[device-sync-in-loop]
+            elig_np = fetch(na_ok, "domain-eligibility") & valid_np
             plan = _domain_plan(
                 batch.spread_topo[start], batch.aff_topo[start],
                 anti_topo_np, batch.match_anti[start], topo_np, valid_np,
@@ -1720,7 +1722,9 @@ def scenario_carry_digest(carry_s: Carry) -> int:
     which is what lets a resumed process verify a snapshot without a
     device round-trip."""
     parts = [_delta.digest_fold(getattr(carry_s, f)) for f in Carry._fields]
-    return _delta.combine_digests(int(jax.device_get(p)) for p in parts)
+    return _delta.combine_digests(
+        int(p) for p in fetch(parts, "carry-digest")
+    )
 
 
 def scenario_carry_digest_host(leaves: dict) -> int:
@@ -1732,7 +1736,7 @@ def scenario_carry_digest_host(leaves: dict) -> int:
 
 def carry_to_host(carry_s: Carry) -> dict:
     """device_get every Carry leaf -> {field: np.ndarray} (snapshot form)."""
-    got = jax.device_get(carry_s)
+    got = fetch(carry_s, "carry-snapshot")
     return {f: np.asarray(getattr(got, f)) for f in Carry._fields}
 
 
@@ -1991,7 +1995,7 @@ def schedule_universes_wave_host(
                 ns_s, carry_s, pods_s, weights_s, choices, filter_on
             )
         )
-        ch = np.asarray(jax.device_get(choices_new))
+        ch = np.asarray(fetch(choices_new, "universes-wave-round"))
         if np.array_equal(ch, prev):
             break
         if rounds > 1:
@@ -2063,7 +2067,7 @@ def schedule_scenarios_host(
     carry_s, nodes, reasons, gpu_take, vg_take, dev_take = schedule_scenarios(
         ns, carry_s, rows, weights_s, valid_s, filter_on
     )
-    got = jax.device_get((nodes, reasons, gpu_take, vg_take, dev_take))
+    got = fetch((nodes, reasons, gpu_take, vg_take, dev_take), "scenarios")
     return (carry_s,) + tuple(np.asarray(a)[:s_real] for a in got)
 
 
@@ -2177,7 +2181,9 @@ def _schedule_scenarios_chunked_host(
                 jnp.int32(count), filter_on,
             )
         )
-        got = jax.device_get((nodes, reasons, gpu_take, vg_take, dev_take))
+        got = fetch(
+            (nodes, reasons, gpu_take, vg_take, dev_take), "scenario-chunk"
+        )
         outs.append(tuple(np.asarray(a)[:, :count] for a in got))
         _metrics.PLAN_CHUNKS.inc()
         if cp is not None:
@@ -2317,7 +2323,7 @@ def _schedule_scenarios_wave_host(
                     jnp.int32(count), filter_on,
                 )
             )
-            ch = np.asarray(jax.device_get(choices_new))
+            ch = np.asarray(fetch(choices_new, "wave-round"))
             if np.array_equal(ch, prev):
                 converged = True
                 break
@@ -2331,7 +2337,7 @@ def _schedule_scenarios_wave_host(
         _metrics.COMMIT_ROUNDS.observe(rounds)
         if converged:
             carry_s = carry_w
-            got = jax.device_get((reasons, gpu_take, vg_take, dev_take))
+            got = fetch((reasons, gpu_take, vg_take, dev_take), "wave-commit")
             outs.append(
                 (np.ascontiguousarray(ch[:, :count]),)
                 + tuple(np.asarray(a)[:, :count] for a in got)
@@ -2348,8 +2354,8 @@ def _schedule_scenarios_wave_host(
                     jnp.int32(count), filter_on,
                 )
             )
-            got = jax.device_get(
-                (nodes, reasons, gpu_take, vg_take, dev_take)
+            got = fetch(
+                (nodes, reasons, gpu_take, vg_take, dev_take), "wave-fallback"
             )
             outs.append(tuple(np.asarray(a)[:, :count] for a in got))
         _metrics.PLAN_CHUNKS.inc()

@@ -66,6 +66,7 @@ from .kernels import (
 )
 from .sanitize import sanitizable
 from .state import pod_rows_from_batch
+from ..utils.tracing import fetch
 
 # Default cap on per-group device-program length (scan steps per dispatch) —
 # one 100k-step scan trips the TPU worker's watchdog, so dispatches are
@@ -364,8 +365,8 @@ def schedule_batch_grouped(
                 extra_filters, extra_scores,
             )
             sl = slice(start + done, start + done + n)
-            nodes_np, reasons_np, take_np, vg_np, dev_np = jax.device_get(
-                (nodes, reasons, take, vg_take, dev_take)
+            nodes_np, reasons_np, take_np, vg_np, dev_np = fetch(
+                (nodes, reasons, take, vg_take, dev_take), "grouped-chunk"
             )
             nodes_out[sl] = nodes_np[:n]
             reasons_out[sl] = reasons_np[:n]

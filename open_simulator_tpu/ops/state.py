@@ -24,6 +24,7 @@ from .encode import (
     selector_table_size,
 )
 from .kernels import Carry, NodeStatic, PodRow
+from ..utils.tracing import fetch
 
 
 def node_static_from_table(enc: Encoder, table: NodeTable) -> NodeStatic:
@@ -196,7 +197,7 @@ def align_carry_scenarios(
     if ns is None:
         return grown
     want = anti_topo_array(enc)
-    have = np.asarray(ns.anti_topo)
+    have = fetch(ns.anti_topo, "align-carry")
     if have.shape != want.shape or not np.array_equal(have, want):
         ns = ns._replace(anti_topo=jnp.asarray(want))
     return grown, ns
@@ -230,7 +231,7 @@ def align_carry(
     # growth: the 0-term pad state and the 1-term state share shape (1,), so a
     # first term registered after NodeStatic was built changes content only.
     want = anti_topo_array(enc)
-    have = np.asarray(ns.anti_topo)
+    have = fetch(ns.anti_topo, "align-carry")
     if have.shape != want.shape or not np.array_equal(have, want):
         ns = ns._replace(anti_topo=jnp.asarray(want))
     return grown, ns

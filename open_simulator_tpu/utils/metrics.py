@@ -694,22 +694,6 @@ HBM_BYTES_PER_DEVICE = REGISTRY.gauge(
     "replicated node-table footprint.",
     labelnames=("device",),
 )
-DEVICE_TIME = REGISTRY.gauge(
-    "osim_device_time_seconds",
-    "Device-side seconds of one warmed call of each audited jit entry, "
-    "from the dispatch-gap analyzer's block_until_ready sandwich "
-    "(utils/profiling.py): wall time between dispatch returning and the "
-    "result becoming ready.",
-    labelnames=("entry",),
-)
-DISPATCH_GAP = REGISTRY.gauge(
-    "osim_dispatch_gap_ratio",
-    "Host->device dispatch-gap fraction per audited jit entry: the share "
-    "of the entry's wall time spent in host-side dispatch (trace-cache "
-    "lookup, argument handling, enqueue) before the device could start — "
-    "the device-idle fraction the profiling layer exists to expose.",
-    labelnames=("entry",),
-)
 
 # Span names that map onto a dedicated kube-parity histogram; everything
 # else lands only in osim_span_duration_seconds{span=...}.

@@ -1,64 +1,24 @@
-"""Device-time profiling: jax.profiler capture + the dispatch-gap analyzer.
+"""Device-time profiling: a jax.profiler capture around any simon command.
 
-The span layer (utils/tracing.py) times host phases; what it cannot see is
-how much of a phase the *device* was actually busy — the evidence the
-wave-commit and capture-round work needs ("how idle is the device during
-the serial commit scan?"). Two tools, both dependency-free beyond jax:
-
-* **Device trace capture** (`capture_device_trace`): a thin wrapper over
-  `jax.profiler.start_trace`/`stop_trace` writing a Perfetto-loadable
-  device trace into a run directory. Exposed as `simon profile <cmd>` and
-  `GET /debug/profile?ms=` on the server. Failures degrade to an
-  `{"ok": false}` report — profiling must never take the run down.
-
-* **Dispatch-gap analyzer** (`analyze_dispatch_gaps`): for each audited
-  jit entry (engine/warmup.registry_captures — the same capture list the
-  audit/warmup/preflight gates prove over), time a warmed call with the
-  block_until_ready sandwich:
-
-      t0 -- fn(*args) returns ------- t1 -- block_until_ready ------- t2
-
-  `t1-t0` is host dispatch time (trace-cache lookup, arg handling,
-  enqueue), `t2-t1` is the device-side remainder the host then waits out.
-  The *dispatch-gap ratio* `dispatch/total` is the fraction of the
-  entry's wall time the device sat idle waiting for the host — the
-  per-entry number published as `osim_dispatch_gap_ratio{entry=}` next to
-  `osim_device_time_seconds{entry=}`, surfaced in bench.py segments as
-  `device_time_ms`/`dispatch_gap_ratio`, and emitted as `device:<entry>`
-  spans so OSIM_TRACE_FILE exports carry device evidence alongside host
-  spans.
-
-Donation caveat: entries that donate buffers consume their inputs, so the
-analyzer re-copies donated args per timed call (the registry's stored args
-stay live — the same discipline as jaxpr_audit._snapshot_donated).
+The span layer (utils/tracing.py) times host phases and marks each span in
+the profiler's host timeline (a TraceAnnotation of the same name), so a
+capture shows what the device did and, where it sat idle, which span the
+host was in. `capture_device_trace` is a thin wrapper over
+`jax.profiler.start_trace`/`stop_trace` writing a Perfetto-loadable trace
+into a run directory, exposed as `simon profile -- <cmd>` and
+`GET /debug/profile?ms=` on the server. Failures degrade to an
+`{"ok": false}` report — profiling must never take the run down.
 """
 
 from __future__ import annotations
 
-import dataclasses
 import os
 import time
-from typing import Any, Dict, List, Optional, Sequence
+from typing import Any, Dict, Optional
 
-from . import metrics
-from .tracing import log, span
+from .tracing import span
 
-__all__ = [
-    "EntryTiming",
-    "DispatchGapReport",
-    "analyze_dispatch_gaps",
-    "capture_device_trace",
-    "profiler_available",
-]
-
-
-def profiler_available() -> bool:
-    try:
-        import jax.profiler  # noqa: F401
-
-        return True
-    except Exception:  # pragma: no cover - jax is a hard dep in-tree
-        return False
+__all__ = ["capture_device_trace"]
 
 
 def capture_device_trace(
@@ -100,151 +60,4 @@ def capture_device_trace(
         return report
     report["ok"] = True
     report["seconds"] = round(time.perf_counter() - t0, 4)
-    return report
-
-
-@dataclasses.dataclass
-class EntryTiming:
-    """Block-until-ready sandwich timing of one warmed jit entry (best of
-    `repeats` runs, so a GC pause can't smear the gap ratio)."""
-
-    name: str
-    dispatch_ms: float  # host time until dispatch returned (the gap)
-    device_ms: float    # dispatch-return -> block_until_ready return
-    total_ms: float
-    gap_ratio: float    # dispatch_ms / total_ms, in [0, 1]
-    repeats: int
-
-    def to_dict(self) -> dict:
-        return {
-            "name": self.name,
-            "dispatch_ms": round(self.dispatch_ms, 4),
-            "device_ms": round(self.device_ms, 4),
-            "total_ms": round(self.total_ms, 4),
-            "gap_ratio": round(self.gap_ratio, 4),
-            "repeats": self.repeats,
-        }
-
-
-@dataclasses.dataclass
-class DispatchGapReport:
-    entries: List[EntryTiming]
-    seconds: float
-
-    @property
-    def device_time_ms(self) -> float:
-        return round(sum(e.device_ms for e in self.entries), 4)
-
-    @property
-    def dispatch_gap_ratio(self) -> float:
-        """Aggregate gap: total dispatch time over total wall time across
-        every timed entry (NOT a mean of ratios — a 2 µs entry must not
-        outvote a 20 ms one)."""
-        total = sum(e.total_ms for e in self.entries)
-        if total <= 0:
-            return 0.0
-        return round(sum(e.dispatch_ms for e in self.entries) / total, 4)
-
-    def to_dict(self) -> dict:
-        return {
-            "entries": [e.to_dict() for e in self.entries],
-            "seconds": round(self.seconds, 4),
-            "device_time_ms": self.device_time_ms,
-            "dispatch_gap_ratio": self.dispatch_gap_ratio,
-        }
-
-    def render_text(self) -> str:
-        lines = [
-            f"dispatch-gap analysis: {len(self.entries)} entries in "
-            f"{self.seconds:.2f}s — device {self.device_time_ms:.2f} ms, "
-            f"aggregate gap ratio {self.dispatch_gap_ratio:.3f}"
-        ]
-        for e in sorted(self.entries, key=lambda e: -e.device_ms):
-            lines.append(
-                f"  {e.name:28s} device {e.device_ms:8.3f} ms  "
-                f"dispatch {e.dispatch_ms:7.3f} ms  gap {e.gap_ratio:.3f}"
-            )
-        return "\n".join(lines)
-
-
-def _fresh_args(cap) -> tuple:
-    """Per-call argument tuple: donated argnums are re-copied so a donating
-    entry can be timed repeatedly without consuming the registry's stored
-    canonical args."""
-    import jax
-
-    donated = set(getattr(cap.fn, "__osim_donate_argnums__", ()) or ())
-    if not donated:
-        return cap.args
-    return tuple(
-        jax.tree.map(lambda a: a.copy() if hasattr(a, "dtype") else a, arg)
-        if i in donated
-        else arg
-        for i, arg in enumerate(cap.args)
-    )
-
-
-def analyze_dispatch_gaps(
-    names: Optional[Sequence[str]] = None,
-    repeats: int = 2,
-    captures: Optional[Sequence[Any]] = None,
-) -> DispatchGapReport:
-    """Time every audited jit entry at its canonical shapes and derive
-    per-entry device ms + dispatch-gap fraction.
-
-    `names` filters the registry (audit names like
-    "ops.fast:schedule_scenarios"); `captures` injects a prepared capture
-    list (tests; anything with .name/.fn/.args/.kwargs works). Each entry
-    is warmed once outside the timed window, then sandwiched `repeats`
-    times, keeping the fastest run. Publishes
-    osim_device_time_seconds{entry=} / osim_dispatch_gap_ratio{entry=} and
-    emits a `device:<entry>` span per entry."""
-    import jax
-
-    if captures is None:
-        from ..engine.warmup import registry_captures
-
-        captures = registry_captures(names)
-    repeats = max(1, int(repeats))
-    t_start = time.perf_counter()
-    entries: List[EntryTiming] = []
-    with span("dispatch-gap-analysis", entries=len(captures)):
-        for cap in captures:
-            # warm outside the timed window: compile (first call in a cold
-            # process) must never be billed as dispatch gap
-            jax.block_until_ready(cap.fn(*_fresh_args(cap), **cap.kwargs))
-            best = None
-            with span(f"device:{cap.name}", entry=cap.name) as dev_span:
-                for _ in range(repeats):
-                    args = _fresh_args(cap)
-                    t0 = time.perf_counter()
-                    out = cap.fn(*args, **cap.kwargs)
-                    t1 = time.perf_counter()
-                    jax.block_until_ready(out)
-                    t2 = time.perf_counter()
-                    if best is None or (t2 - t0) < best[2]:
-                        best = (t1 - t0, t2 - t1, t2 - t0)
-                dispatch_s, device_s, total_s = best
-                gap = dispatch_s / total_s if total_s > 0 else 0.0
-                dev_span.meta.update(
-                    device_ms=round(device_s * 1e3, 4),
-                    dispatch_ms=round(dispatch_s * 1e3, 4),
-                    gap_ratio=round(gap, 4),
-                )
-            entries.append(
-                EntryTiming(
-                    name=cap.name,
-                    dispatch_ms=dispatch_s * 1e3,
-                    device_ms=device_s * 1e3,
-                    total_ms=total_s * 1e3,
-                    gap_ratio=gap,
-                    repeats=repeats,
-                )
-            )
-            metrics.DEVICE_TIME.set(device_s, entry=cap.name)
-            metrics.DISPATCH_GAP.set(gap, entry=cap.name)
-    report = DispatchGapReport(
-        entries=entries, seconds=time.perf_counter() - t_start
-    )
-    log.debug("dispatch-gap analysis:\n%s", report.render_text())
     return report

@@ -17,10 +17,23 @@ Spans nest via a thread-local stack; finished roots are kept in a bounded
 ring buffer for the server endpoint (size: OSIM_SPAN_HISTORY, default 64).
 Every finished span also feeds the metrics histograms (utils/metrics.py),
 and when OSIM_TRACE_FILE is set, finished root trees are exported as Chrome
-trace events (load the file in Perfetto / chrome://tracing). Overhead when
-disabled is two clock reads per span — safe to leave in hot host paths
-(device time is measured as host wall time around blocking calls, which is
-what a user can act on).
+trace events (load the file in Perfetto / chrome://tracing). A span costs
+10–20 µs of host time — safe to leave in hot host paths (device time is
+measured as host wall time around blocking calls, which is what a user can
+act on).
+
+Device-trace twin: every span also opens `jax.profiler.TraceAnnotation(name)`
+for its duration, so a `jax.profiler` capture (`simon profile -- <cmd>`, the
+benchmark's `--trace 1`) shows each span on the calling thread's host line,
+on the same clock as the device's operations: an idle stretch of the device
+is named by the innermost span open over it. Only the name goes to the
+profiler, never the meta (JAX would fold it into the event name and split
+one span into many labels); with no capture running it costs ~0.5 µs.
+
+Host syncs: `fetch(tree, site)` is the one device->host read of the
+simulate path — `jax.device_get` inside a `host-sync` span with `site` in
+its meta — so a call's `host-sync` count is its host round trips and their
+time is what the host waited on the device.
 
 Cross-thread propagation: every span carries explicit trace/span IDs. A
 `TraceContext` snapshot of the active span (`current_context()`) can cross a
@@ -37,6 +50,7 @@ args, so an exported file is reconnectable offline.
 
 from __future__ import annotations
 
+import functools
 import json
 import logging
 import os
@@ -198,13 +212,23 @@ _history: List[dict] = []
 _history_lock = threading.Lock()
 
 
+@functools.cache
+def _trace_annotation():
+    """jax.profiler.TraceAnnotation, imported on first use so that importing
+    this module does not import jax."""
+    from jax.profiler import TraceAnnotation
+
+    return TraceAnnotation
+
+
 @contextmanager
 def span(name: str, **meta):
     """Time a phase. Nested spans build a tree; every finished span observes
     into the metrics histograms, and when a ROOT span closes it is recorded
     for /debug/timings, exported to OSIM_TRACE_FILE (if set), logged at
     DEBUG, and escalated to WARNING with its full subtree when slower than
-    OSIM_SLOW_TRACE seconds (the LogIfLong analog)."""
+    OSIM_SLOW_TRACE seconds (the LogIfLong analog). For its duration the
+    span is also a profiler TraceAnnotation of the same name (no meta)."""
     s = Span(name)
     if meta:
         s.meta.update(meta)
@@ -222,7 +246,8 @@ def span(name: str, **meta):
         s.trace_id = _new_trace_id()
     _tracer.stack.append(s)
     try:
-        yield s
+        with _trace_annotation()(name):
+            yield s
     finally:
         s.end = time.time()
         _tracer.stack.pop()
@@ -238,6 +263,16 @@ def span(name: str, **meta):
                 log.warning("slow trace (> %.1fs):\n%s", SLOW_TRACE_S, s.render())
             else:
                 log.debug("trace:\n%s", s.render())
+
+
+def fetch(tree, site: str):
+    """`jax.device_get(tree)` inside a `host-sync` span naming `site`: the
+    one way the simulate path reads device values on the host. Fetch
+    everything one step needs in one call — each call is a round trip."""
+    import jax
+
+    with span("host-sync", site=site):
+        return jax.device_get(tree)
 
 
 @contextmanager

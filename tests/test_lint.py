@@ -10,6 +10,8 @@ anything.
 import json
 import textwrap
 
+import pytest
+
 from open_simulator_tpu.analysis import iter_rules, run_lint
 from open_simulator_tpu.analysis.lint import build_context
 
@@ -424,6 +426,56 @@ def test_device_sync_near_miss_consolidated_device_get(tmp_path):
         """,
     )
     assert "device-sync-in-loop" not in _rule_ids(r)
+
+
+_TRACING_FETCH = {
+    "tracing": """
+        import jax
+
+        def fetch(tree, site):
+            return jax.device_get(tree)
+        """,
+}
+
+
+@pytest.mark.parametrize("wrapper,flagged", [
+    # the tracing fetch makes a wrapper host-returning, like device_get
+    ("return fetch(kern(x), 'site')", False),
+    ("return jax.device_get(kern(x))", False),
+    # control: a wrapper that hands back the jit result is jit-ish
+    ("return kern(x)", True),
+])
+def test_device_sync_tracing_fetch_is_a_consolidated_fetch(
+    tmp_path, wrapper, flagged
+):
+    r = _lint(
+        tmp_path,
+        f"""
+        import jax
+        import numpy as np
+
+        from .tracing import fetch
+
+        @jax.jit
+        def kern(x):
+            return x * 2
+
+        def kern_host(x):
+            {wrapper}
+
+        def drive(xs):
+            out = []
+            for x in xs:
+                y = kern_host(x)
+                out.append(np.asarray(y))
+                z = fetch(kern(x), "in-loop")
+                out.append(np.asarray(z))
+            return out
+        """,
+        extra_modules=_TRACING_FETCH,
+    )
+    hits = [f for f in r.active if f.rule == "device-sync-in-loop"]
+    assert len(hits) == int(flagged)
 
 
 def test_device_sync_suppression_escape(tmp_path):

@@ -1,104 +1,12 @@
-"""Device-time profiling (utils/profiling.py): dispatch-gap analyzer +
-jax.profiler capture wrapper, plus the bench/CLI surfaces that expose them."""
+"""Device-time profiling (utils/profiling.py): the jax.profiler capture
+wrapper, the spans it shows on the host timeline, and `simon profile`."""
 
+import jax
 import jax.numpy as jnp
-import pytest
 
-from open_simulator_tpu.utils import metrics, profiling, tracing
-from open_simulator_tpu.utils.profiling import (
-    DispatchGapReport,
-    EntryTiming,
-    analyze_dispatch_gaps,
-    capture_device_trace,
-)
-
-
-class _Cap:
-    """Minimal stand-in for a jaxpr_audit capture: .name/.fn/.args/.kwargs."""
-
-    def __init__(self, name, fn, args=(), kwargs=None):
-        self.name = name
-        self.fn = fn
-        self.args = args
-        self.kwargs = kwargs or {}
-
-
-def _caps():
-    return [
-        _Cap("t:add", lambda a, b: a + b, (jnp.ones(64), jnp.ones(64))),
-        _Cap("t:sum", lambda a: jnp.sum(a * a), (jnp.arange(128.0),)),
-    ]
-
-
-def test_analyze_dispatch_gaps_times_every_entry():
-    rep = analyze_dispatch_gaps(captures=_caps(), repeats=2)
-    assert [e.name for e in rep.entries] == ["t:add", "t:sum"]
-    for e in rep.entries:
-        assert e.total_ms > 0
-        assert e.dispatch_ms >= 0 and e.device_ms >= 0
-        assert 0.0 <= e.gap_ratio <= 1.0
-        assert e.repeats == 2
-        # the sandwich decomposes the total exactly
-        assert e.dispatch_ms + e.device_ms == pytest.approx(
-            e.total_ms, rel=1e-6
-        )
-    # the report property rounds to 4 decimals
-    assert rep.device_time_ms == pytest.approx(
-        sum(e.device_ms for e in rep.entries), abs=1e-4
-    )
-
-
-def test_analyze_publishes_metrics_and_device_spans():
-    analyze_dispatch_gaps(captures=_caps(), repeats=1)
-    assert metrics.DEVICE_TIME.value(entry="t:add") >= 0.0
-    assert 0.0 <= metrics.DISPATCH_GAP.value(entry="t:sum") <= 1.0
-    root = [
-        r for r in tracing.recent_timings()
-        if r["name"] == "dispatch-gap-analysis"
-    ][-1]
-    dev = {c["name"]: c for c in root["children"]}
-    assert "device:t:add" in dev and "device:t:sum" in dev
-    meta = dev["device:t:sum"]["meta"]
-    assert {"entry", "device_ms", "dispatch_ms", "gap_ratio"} <= set(meta)
-
-
-def test_aggregate_gap_is_time_weighted_not_mean_of_ratios():
-    """A tiny all-dispatch entry must not outvote a big all-device one:
-    the aggregate is sum(dispatch)/sum(total), not mean(gap_ratio)."""
-    rep = DispatchGapReport(
-        entries=[
-            EntryTiming("tiny", dispatch_ms=1.0, device_ms=0.0,
-                        total_ms=1.0, gap_ratio=1.0, repeats=1),
-            EntryTiming("big", dispatch_ms=0.0, device_ms=99.0,
-                        total_ms=99.0, gap_ratio=0.0, repeats=1),
-        ],
-        seconds=0.1,
-    )
-    assert rep.dispatch_gap_ratio == 0.01  # not (1.0 + 0.0) / 2
-    assert rep.device_time_ms == 99.0
-    d = rep.to_dict()
-    assert d["dispatch_gap_ratio"] == 0.01
-    assert [e["name"] for e in d["entries"]] == ["tiny", "big"]
-    assert "aggregate gap ratio 0.010" in rep.render_text()
-
-
-def test_fresh_args_recopies_donated_argnums():
-    """A donating entry consumes its inputs; the analyzer must hand it a
-    fresh copy per call so the registry's canonical args stay live."""
-
-    def fn(a, b):
-        return a + b
-
-    fn.__osim_donate_argnums__ = (0,)
-    a, b = jnp.ones(8), jnp.ones(8)
-    cap = _Cap("t:donate", fn, (a, b))
-    fresh = profiling._fresh_args(cap)
-    assert fresh[0] is not a        # donated: re-copied
-    assert fresh[1] is b            # non-donated: passed through
-    assert (fresh[0] == a).all()
-    # no donation marker -> the stored tuple is reused as-is
-    cap2 = _Cap("t:plain", lambda x: x, (a,))
-    assert profiling._fresh_args(cap2) is cap2.args
+from benchmark import trace as trace_mod
+from open_simulator_tpu.utils import tracing
+from open_simulator_tpu.utils.profiling import capture_device_trace
 
 
 def test_capture_device_trace_writes_into_out_dir(tmp_path):
@@ -121,75 +29,35 @@ def test_capture_device_trace_failure_degrades_not_raises(tmp_path):
     assert "profiled workload exploded" in rep["error"]
 
 
-def test_bench_segment_device_fields_default_null(monkeypatch, capsys):
-    import json
-
-    import bench
-
-    monkeypatch.delenv("OSIM_DEVICE_PROFILE", raising=False)
-    monkeypatch.setitem(
-        bench.CONFIGS, "null_probe", lambda: {"elapsed_s": 0.0}
-    )
-    rc = bench._segment_main("null_probe", 0, 0)
-    assert rc == 0
-    out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert out["device_time_ms"] is None
-    assert out["dispatch_gap_ratio"] is None
-    assert "device_profile" not in out
-
-
-def test_bench_segment_device_fields_filled_under_env(monkeypatch, capsys):
-    import json
-
-    import bench
-    from open_simulator_tpu.utils import profiling as prof_mod
-
-    monkeypatch.setenv("OSIM_DEVICE_PROFILE", "1")
-    monkeypatch.setattr(
-        prof_mod, "registry_captures", lambda names=None: _caps(),
-        raising=False,
-    )
-    # route the registry lookup through the injected captures
-    orig = prof_mod.analyze_dispatch_gaps
-    monkeypatch.setattr(
-        prof_mod,
-        "analyze_dispatch_gaps",
-        lambda names=None, repeats=2, captures=None: orig(
-            captures=_caps(), repeats=repeats
-        ),
-    )
-    monkeypatch.setitem(
-        bench.CONFIGS, "null_probe", lambda: {"elapsed_s": 0.0}
-    )
-    rc = bench._segment_main("null_probe", 0, 0)
-    assert rc == 0
-    out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert out["device_time_ms"] is not None and out["device_time_ms"] >= 0
-    assert 0.0 <= out["dispatch_gap_ratio"] <= 1.0
-    assert [e["name"] for e in out["device_profile"]["entries"]] == [
-        "t:add", "t:sum",
+def test_span_is_a_profiler_annotation_without_meta(tmp_path):
+    """Inside a capture, each span is a host event of exactly its name (no
+    `#k=v#` meta folded in), nested on the calling thread's line — the
+    line the benchmark labels idle device gaps from."""
+    out = str(tmp_path / "annot")
+    jax.profiler.start_trace(out)
+    try:
+        with tracing.span("probe-outer", pods=3):
+            with tracing.span("probe-inner", site="x"):
+                tracing.fetch(jnp.arange(4) * 2, "probe")
+    finally:
+        jax.profiler.stop_trace()
+    lines = [
+        ln for pl in trace_mod.load(out) if pl["name"].startswith("/host")
+        for ln in pl["lines"]
+        if any(ev[0] == "probe-outer" for ev in ln["events"])
     ]
+    assert len(lines) == 1
+    ev = {name: (s, e) for name, s, e in lines[0]["events"]
+          if name in ("probe-outer", "probe-inner", "host-sync")}
+    assert set(ev) == {"probe-outer", "probe-inner", "host-sync"}
+    assert ev["probe-outer"][0] <= ev["probe-inner"][0]
+    assert ev["probe-inner"][1] <= ev["probe-outer"][1]
+    assert ev["probe-inner"][0] <= ev["host-sync"][0]
+    assert not any("#" in ev[0] for ln in lines for ev in ln["events"])
 
 
-def test_cli_profile_gaps_json(monkeypatch, capsys):
-    import json
-
+def test_profile_needs_a_command(capsys):
     from open_simulator_tpu.cli import main as cli
-    from open_simulator_tpu.utils import profiling as prof_mod
 
-    orig = prof_mod.analyze_dispatch_gaps
-    monkeypatch.setattr(
-        prof_mod,
-        "analyze_dispatch_gaps",
-        lambda names=None, repeats=2, captures=None: orig(
-            captures=_caps(), repeats=repeats
-        ),
-    )
-    rc = cli.main(["profile", "--format", "json"])
-    assert rc == 0
-    out = capsys.readouterr().out
-    doc = json.loads(out[out.index("{"):])
-    assert "trace" not in doc  # no command given -> analyzer only
-    entries = doc["dispatch_gaps"]["entries"]
-    assert [e["name"] for e in entries] == ["t:add", "t:sum"]
-    assert all(e["repeats"] == 3 for e in entries)
+    assert cli.main(["profile"]) == 2
+    assert "needs a command" in capsys.readouterr().err
