@@ -397,10 +397,9 @@ class Simulator:
         self._pending_cluster: List[Pod] = []
         for pod in cluster.pods:
             if pod.node_name:
-                # Copy: preemption may evict pre-bound pods (clearing
-                # node_name/phase/annotations), and the caller's cluster must
-                # stay pristine for re-simulation by the capacity search.
-                self._bound.append((copy.deepcopy(pod), pod.node_name))
+                # Borrowed, not copied: nothing changes a pod that arrives
+                # bound except eviction, and _evict copies it first.
+                self._bound.append((pod, pod.node_name))
             elif pod.scheduler_name in self._profiles:
                 # Copy: scheduling mutates node_name/phase, and the caller's
                 # cluster must stay pristine for re-simulation (the capacity
@@ -419,6 +418,8 @@ class Simulator:
                     "ignoring pending cluster pod %s: no scheduler profile "
                     "named %r", pod.key, pod.scheduler_name,
                 )
+        # ids of the caller's pods in _bound: read-only to the engine
+        self._borrowed = {id(p) for p, _ in self._bound}
         # Cluster daemonsets expand against the final node list (core.go:85-96).
         for ds in cluster.daemonsets:
             ds_pods = pods_from_workload(ds, nodes=cluster.nodes)
@@ -1165,13 +1166,16 @@ class Simulator:
             # The host-side victim model covers resources only; the device
             # retry additionally enforces spread/affinity/storage/GPU. Snapshot
             # everything eviction touches so a failed retry rolls back instead
-            # of leaving pods evicted for nothing.
+            # of leaving pods evicted for nothing. A borrowed victim is never
+            # reset (_evict resets its copy), so it has no fields to restore.
             snapshot = (
                 self._carry,
                 list(self._bound),
                 dict(self._storage_takes),
                 len(self._preempted),
-                self._snapshot_bindings(res.victims),
+                self._snapshot_bindings(
+                    [v for v in res.victims if id(v) not in self._borrowed]
+                ),
             )
             self._evict(res.victims, res.node, by=pod.key)
             # Reschedule the preemptor now that room exists. The reference
@@ -1198,7 +1202,9 @@ class Simulator:
         return still_failed
 
     def _evict(self, victims: List[Pod], node_name: str, by: str) -> None:
-        """Remove victims from a node and reverse their carry contributions."""
+        """Remove victims from a node and reverse their carry contributions.
+        A borrowed victim (the caller's pre-bound pod) is recorded and reset
+        as the engine's own copy; the caller's object stays as it was."""
         victim_keys = {id(v) for v in victims}
         self._bound = [
             (p, n) for p, n in self._bound if id(p) not in victim_keys
@@ -1246,6 +1252,9 @@ class Simulator:
             for aid in self.enc.anti_ids(v):
                 if aid < anti.shape[0]:
                     anti[aid, ni] -= 1.0
+            if id(v) in self._borrowed:
+                v = copy.deepcopy(v)
+                metrics.BOUND_POD_COPIES.inc()
             self._reset_bindings([v])
             self._preempted.append(PreemptedPod(pod=v, node=node_name, by=by))
         self._carry = self._carry._replace(
@@ -1256,9 +1265,12 @@ class Simulator:
         self._reshard()
 
     # The engine's FULL mutation surface on a pod is node_name / phase / the
-    # gpu-index annotation (placement at _schedule_run, eviction at _evict) —
-    # everything else is tracked outside the object. The three helpers below
-    # are the only places that field set appears; extend all of them together.
+    # gpu-index annotation (placement at _bind_placed, eviction at _evict) —
+    # everything else is tracked outside the object. Only pods this engine
+    # owns are changed: the caller's pre-bound pods are borrowed read-only
+    # (_borrowed), and _evict resets a copy of one, never the object itself.
+    # The three helpers below and _bind_placed are the only places that field
+    # set appears; extend all of them together.
 
     @staticmethod
     def _reset_bindings(pods: List[Pod]) -> None:

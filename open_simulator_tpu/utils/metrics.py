@@ -432,6 +432,12 @@ PREEMPTION_ATTEMPTS = REGISTRY.counter(
     "Preemption attempts for unscheduled pods, by outcome.",
     labelnames=("outcome",),
 )
+BOUND_POD_COPIES = REGISTRY.counter(
+    "osim_bound_pod_copies_total",
+    "Pre-bound cluster pods copied because preemption evicted them, "
+    "rolled-back evictions included (simulate borrows the caller's bound "
+    "pods and copies only these).",
+)
 EXTENDER_REQUESTS = REGISTRY.counter(
     "osim_extender_requests_total",
     "HTTP scheduler-extender round trips, by verb and outcome.",

@@ -2,6 +2,8 @@
 search re-probes the same ClusterResource many times (code-review finding:
 pending cluster pods were bound in place, corrupting later probes)."""
 
+import copy
+
 import yaml
 
 from open_simulator_tpu.core.objects import Node, Pod
@@ -37,6 +39,61 @@ def test_simulate_does_not_mutate_cluster_pods():
     r2 = simulate(cluster, [])                      # identical re-run
     assert not r1.unscheduled and not r2.unscheduled
     assert [len(s.pods) for s in r1.node_status] == [len(s.pods) for s in r2.node_status]
+
+
+def _bound_cluster():
+    """A GPU node and a plain node holding pre-bound pods (one with a
+    gpu-index annotation), one pending pod and a two-pod app."""
+    gpu = {"alibabacloud.com/gpu-count": "2", "alibabacloud.com/gpu-mem": "32Gi"}
+    nodes = [
+        Node.from_dict({
+            "metadata": {"name": name, "labels": {"kubernetes.io/hostname": name}},
+            "status": {"allocatable": {"cpu": "8", "memory": "16Gi", "pods": "110", **extra}},
+        })
+        for name, extra in (("g0", gpu), ("n1", {}))
+    ]
+
+    def pod(name, node="", annotations=None):
+        return Pod.from_dict({
+            "metadata": {"name": name, "namespace": "d", "annotations": annotations or {}},
+            "spec": {
+                "nodeName": node,
+                "containers": [
+                    {"name": "c", "image": "img", "resources": {"requests": {"cpu": "1", "memory": "1Gi"}}}
+                ],
+            },
+        })
+
+    pods = [
+        pod("gpu-bound", "g0", {
+            "alibabacloud.com/gpu-mem": "4Gi",
+            "alibabacloud.com/gpu-count": "1",
+            "alibabacloud.com/gpu-index": "1",
+        }),
+        pod("bound", "n1"),
+        pod("pending"),
+    ]
+    for p in pods[:2]:
+        p.phase = "Running"
+    apps = [AppResource(name="a", objects=[
+        pod(f"app{i}").raw | {"kind": "Pod"} for i in range(2)
+    ])]
+    return ClusterResource(nodes=nodes, pods=pods), apps
+
+
+def test_simulate_borrows_bound_pods_and_leaves_them_unchanged():
+    cluster, apps = _bound_cluster()
+    snapshot = copy.deepcopy(cluster.pods)
+    r1 = simulate(cluster, apps)
+    r2 = simulate(cluster, apps)
+    assert cluster.pods == snapshot
+    assert cluster.pods[0].meta.annotations["alibabacloud.com/gpu-index"] == "1"
+    assert r1 == r2
+    assert not r1.unscheduled and not r1.preempted
+    listed = {id(p) for st in r1.node_status for p in st.pods}
+    assert {id(p) for p in cluster.pods[:2]} <= listed   # the caller's objects
+    assert id(cluster.pods[2]) not in listed              # pending: a copy is placed
+    assert sum(len(st.pods) for st in r1.node_status) == 5
 
 
 def test_fake_node_names_unique_and_stable():

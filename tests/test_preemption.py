@@ -6,6 +6,8 @@ filterPodsWithPDBViolation :736, pickOneNodeForPreemption :443,
 PodEligibleToPreemptOthers :231).
 """
 
+import copy
+
 import numpy as np
 
 from open_simulator_tpu.core.objects import LabelSelector, Node, Pod
@@ -20,8 +22,11 @@ from open_simulator_tpu.engine.preemption import (
 from open_simulator_tpu.engine.simulator import (
     AppResource,
     ClusterResource,
+    Simulator,
+    UnscheduledPod,
     simulate,
 )
+from open_simulator_tpu.utils import metrics
 
 
 def mknode(name, cpu="4", mem="8Gi", taints=None):
@@ -312,6 +317,101 @@ def test_device_fits_eviction_clears_anti_affinity_conflict():
         for p in st.pods
     }
     assert placed == {"pre": "solo"}
+
+
+def _borrowed_victim_case():
+    """The setup above with the preemptor as an app pod and a GPU victim
+    (gpu-index annotation) bound by the caller."""
+    node = Node.from_dict(
+        {
+            "metadata": {
+                "name": "solo", "labels": {"kubernetes.io/hostname": "solo"}
+            },
+            "status": {
+                "allocatable": {
+                    "cpu": "4", "memory": "8Gi", "pods": "110",
+                    "alibabacloud.com/gpu-count": "1",
+                    "alibabacloud.com/gpu-mem": "16Gi",
+                }
+            },
+        }
+    )
+    victim = mkpod("victim", cpu="3", priority=1, labels={"app": "bad"})
+    victim.meta.annotations.update({
+        "alibabacloud.com/gpu-mem": "8Gi",
+        "alibabacloud.com/gpu-count": "1",
+        "alibabacloud.com/gpu-index": "0",
+    })
+    pre = mkpod("pre", cpu="3", priority=100).raw
+    pre["spec"]["affinity"] = {
+        "podAntiAffinity": {
+            "requiredDuringSchedulingIgnoredDuringExecution": [
+                {
+                    "labelSelector": {"matchLabels": {"app": "bad"}},
+                    "topologyKey": "kubernetes.io/hostname",
+                }
+            ]
+        }
+    }
+    cluster = ClusterResource(nodes=[node], pods=bound("solo", victim))
+    return cluster, [AppResource(name="critical", objects=[pre | {"kind": "Pod"}])]
+
+
+def _placements(result):
+    return {
+        p.meta.name: st.node.name for st in result.node_status for p in st.pods
+    }
+
+
+def test_preemption_copies_borrowed_victim_and_leaves_caller_pod():
+    cluster, apps = _borrowed_victim_case()
+    victim = cluster.pods[0]
+    snapshot = copy.deepcopy(victim)
+    copies = metrics.BOUND_POD_COPIES.value()
+    r1 = simulate(cluster, apps)
+    assert victim == snapshot
+    assert (victim.node_name, victim.phase) == ("solo", "Running")
+    assert victim.meta.annotations["alibabacloud.com/gpu-index"] == "0"
+    assert not r1.unscheduled
+    assert _placements(r1) == {"pre": "solo"}
+    assert len(r1.preempted) == 1
+    evicted = r1.preempted[0].pod
+    assert evicted is not victim and evicted.meta.name == "victim"
+    assert (evicted.node_name, evicted.phase) == ("", "Pending")
+    assert "alibabacloud.com/gpu-index" not in evicted.meta.annotations
+    assert metrics.BOUND_POD_COPIES.value() == copies + 1
+    r2 = simulate(cluster, apps)
+    assert _placements(r2) == _placements(r1)
+    assert r2.preempted == r1.preempted
+    assert victim == snapshot
+
+
+def test_preemption_rollback_leaves_borrowed_victim_bound(monkeypatch):
+    """The retry after eviction fails: the eviction rolls back, and the
+    caller's pod is still bound, listed on its node and not preempted."""
+    cluster, apps = _borrowed_victim_case()
+    victim = cluster.pods[0]
+    snapshot = copy.deepcopy(victim)
+    real = Simulator._schedule_batch_host
+    seen = []
+
+    def fail_retry(self, pods):
+        if any(p.meta.name == "pre" for p in pods):
+            seen.append(pods)
+            if len(seen) == 2:   # the retry after the eviction
+                return [UnscheduledPod(pod=p, reason="forced") for p in pods]
+        return real(self, pods)
+
+    monkeypatch.setattr(Simulator, "_schedule_batch_host", fail_retry)
+    copies = metrics.BOUND_POD_COPIES.value()
+    result = simulate(cluster, apps)
+    assert len(seen) == 2
+    assert victim == snapshot
+    assert not result.preempted
+    assert [u.pod.meta.name for u in result.unscheduled] == ["pre"]
+    assert [p for st in result.node_status for p in st.pods] == [victim]
+    assert result.node_status[0].pods[0] is victim
+    assert metrics.BOUND_POD_COPIES.value() == copies + 1
 
 
 def test_lane_parallel_driver_matches_sequential():

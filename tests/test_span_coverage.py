@@ -87,12 +87,12 @@ def test_host_syncs_per_call_are_positive_and_repeat():
 def test_simulator_run_opens_one_root_per_run():
     sim = Simulator(_cluster())
     assert _last_root("prepare-cluster")["meta"] == {"pods": 0}
-    before = len([r for r in tracing.recent_timings()
-                  if r["name"] == "simulate"])
+    # by span id: the history is a ring, so a full one keeps its length
+    seen = {r["span_id"] for r in tracing.recent_timings()}
     sim.run(_apps())
-    roots = [r for r in tracing.recent_timings() if r["name"] == "simulate"]
-    assert len(roots) == before + 1
-    assert [c["name"] for c in roots[-1]["children"]] == SEVEN[1:]
+    new = [r for r in tracing.recent_timings() if r["span_id"] not in seen]
+    assert [r["name"] for r in new] == ["simulate"]
+    assert [c["name"] for c in new[0]["children"]] == SEVEN[1:]
 
 
 def test_fetch_returns_host_values_inside_a_host_sync_span():
