@@ -2,7 +2,13 @@ import os
 
 import pytest
 
-from benchmark.tests import tiny
+pytest.register_assert_rewrite("benchmark.tests.helpers")
+
+from benchmark.tests import tiny  # noqa: E402
+
+# files that a later PR would add (test_cells.py lays them on a copy),
+# among them a test_kind_<kind>.py that runs only there
+collect_ignore = ["fixtures"]
 
 
 @pytest.fixture(scope="session")

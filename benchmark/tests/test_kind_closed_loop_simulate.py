@@ -1,13 +1,18 @@
-"""The harness with the timed path broken underneath it: `correct` has to
-come out false for each fault a scheduling cell can have. The faults are
-planted in the commit engine's entry as Simulator calls it, and, for the
-tie-break, in the cluster `simulate` is handed."""
+"""The kind `closed_loop_simulate` with its timed path broken underneath it:
+`correct` has to come out false for each fault a scheduling cell can have,
+and for the control. The faults are planted in the commit engine's entry as
+Simulator calls it, and, for the tie-break, in the cluster `simulate` is
+handed. The control puts the reference's own bfloat16 choices in the
+program's place, on three seeds of each cell. The check names read here are
+those of this kind's reference, benchmark/reference/replay.py."""
 
 import numpy as np
 import pytest
 
 from benchmark.tests.helpers import cells, run_cell
 from benchmark.tests.tiny import REPO
+
+CELLS = cells(REPO, kind="closed_loop_simulate")
 
 
 def _broken(real, fault):
@@ -44,7 +49,7 @@ def _ties_to_highest(real):
 
 @pytest.mark.parametrize(
     "fault", ("answer_altered", "half_left_out", "state_unchanged", "ties_to_highest"))
-@pytest.mark.parametrize("cell", cells(REPO))
+@pytest.mark.parametrize("cell", CELLS)
 def test_fault_is_caught(tiny_root, cell, fault, monkeypatch):
     from open_simulator_tpu.engine import simulator
 
@@ -59,3 +64,12 @@ def test_fault_is_caught(tiny_root, cell, fault, monkeypatch):
         checks = res["checks"]
         assert checks["ties_lost"]["value"] > 0
         assert checks["score_gap_max"]["value"] <= checks["score_gap_max"]["limit"]
+
+
+@pytest.mark.parametrize("seed", (7, 1_000_000_007, 4_000_000_001))
+@pytest.mark.parametrize("cell", CELLS)
+def test_control_is_not_correct(tiny_root, cell, seed):
+    res = run_cell(tiny_root, cell, seed, "--control", "1")
+    gap = res["checks"]["score_gap_max"]
+    assert gap["value"] > gap["limit"]
+    assert not res["correct"]

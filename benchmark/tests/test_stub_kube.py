@@ -1,19 +1,15 @@
 """The stub Kubernetes API gives the program's live snapshot the same
 cluster that the generator makes directly from the seed."""
 
-import os
-
-from benchmark import generator, stub_kube
-from benchmark.tests.tiny import REPO, TINY_CONFIG
+from benchmark import stub_kube
+from benchmark.tests.tiny import REPO, shrunk
 
 SEED = 2_147_483_659  # above 32 signed bits
 
 
 def _cfg():
-    cfg = generator.load_json(os.path.join(REPO, "benchmark/configs/envelope_5k.json"))
-    cfg["node_groups"][0]["count"] = TINY_CONFIG["envelope_5k"]["nodes"]
-    cfg["bound"]["count"] = TINY_CONFIG["envelope_5k"]["bound"]
-    return cfg
+    """The envelope at its tiny size, from its overlay."""
+    return shrunk(REPO, "configs", "envelope_5k", "benchmark/configs/envelope_5k.json")
 
 
 def _facts(cluster):
@@ -32,6 +28,6 @@ def test_snapshot_through_stub_equals_direct_cluster():
     direct = ClusterResource.from_objects(nodes + pods)
     with stub_kube.serve(cfg, SEED) as url:
         live = snapshot_cluster(KubeClient(KubeConfig(server=url)))
-    assert len(live.nodes) == TINY_CONFIG["envelope_5k"]["nodes"]
-    assert len(live.pods) == TINY_CONFIG["envelope_5k"]["bound"]
+    assert len(live.nodes) == cfg["node_groups"][0]["count"] == 50
+    assert len(live.pods) == cfg["bound"]["count"] == 1000
     assert _facts(live) == _facts(direct)
