@@ -633,9 +633,8 @@ def config_preempt(n_nodes=60, n_low=400, n_high=100):
     cluster (400 x 1cpu on 60 x 8cpu = 80 cpu headroom), then a
     high-priority tier (100 x 2cpu, priority 100) arrives: ~40 pods fit in
     the headroom and the rest must evict low-priority victims through the
-    lane-parallel batched probe path (engine/preemption.try_preempt with
-    fits_many_fn). Measures the cost the reference pays in
-    selectVictimsOnNode's per-node filter dry runs
+    device victim search (ops/preempt.py). Measures the cost the reference
+    pays in selectVictimsOnNode's per-node filter dry runs
     (default_preemption.go:578-626)."""
     from open_simulator_tpu.engine.simulator import (
         AppResource,
@@ -657,8 +656,8 @@ def config_preempt(n_nodes=60, n_low=400, n_high=100):
         return time.time() - t0, result
 
     n_pods = n_low + n_high
-    # Cold: compiles dominate (every probe lane-bucket shape traces its own
-    # vmapped run_filters). Warm: a second identical run in the same process
+    # Cold: compiles dominate (the search, eviction and table-update
+    # programs). Warm: a second identical run in the same process
     # reuses every executable — the steady state a server-mode or capacity-
     # search caller sees, and what the persistent XLA cache gives a fresh
     # process. The reference pays neither (plain Go calls) but its per-probe

@@ -19,16 +19,22 @@ defaultpreemption/default_preemption.go`:
     (the reference's final earliest-start-time tiebreaks have no analog here:
     the simulation has no pod start times)
 
-Deviation (documented): feasibility during victim selection checks the
-resolvable filters host-side — resources (CPU/mem/pods/extended) — on top of
-the static unresolvable gate. Topology-spread/inter-pod-affinity/storage/GPU
-coupling to victims is not modeled; the upstream plugin itself skips
-affinity-to-victim coupling "for performance reasons" (:628-632).
+Where it runs: the engine's victim search is ops/preempt.py, one device
+program per preemptor over every node at once. Each node's fit in the
+reprieve loop is every filter plugin of the pod's profile on the cluster as
+it would be after that node's evictions (the reference's dry run of the
+filters, :598-626): node-local filters read the node's own column, and
+topology spread and inter-pod (anti)affinity read domain counts in which
+only that node's domain changes, exactly. Out-of-tree filters may read the
+whole carry, so with any of them each node runs the filters over the whole
+cluster instead. The host reads one answer per preemptor; the victims'
+eviction is a device update of the carry (Simulator._evict). In a cluster of
+priority tiers this is the common path, not a rare one.
 
-This runs host-side: preemption is rare (only failed pods with priority > 0),
-and its victim search is branch-heavy sequential logic that would serialize on
-device anyway — the TPU path stays a pure batch scheduler, and preemption
-re-syncs device state once per successful eviction round.
+This module keeps the same semantics on the host: `try_preempt` with a
+`fits_fn` (the resources-only `_fits` by default) drives the reprieve lanes
+one probe at a time. The tests hold the device search to it, with the
+filters evaluated independently on each post-eviction cluster.
 """
 
 from __future__ import annotations
@@ -363,16 +369,17 @@ def try_preempt(
     fits_many_fn=None,
     extenders=(),
 ) -> Optional[PreemptionResult]:
-    """Full PostFilter: find the best node + minimal victim set, or None.
+    """Full PostFilter on the host: find the best node + minimal victim set,
+    or None. The engine runs the same semantics on the device
+    (ops/preempt.py, Simulator._search_victims); this driver is what it is
+    held to.
 
-    `fits_many_fn(pod, [(node, remaining), ...]) -> [bool]` enables the
-    lane-parallel driver: every candidate node advances its reprieve loop in
-    lockstep rounds, so one preemptor costs 1 + max(queue length) batched fit
-    evaluations instead of sum over nodes of (1 + queue length) single
-    probes. Per-lane semantics are identical to select_victims_on_node —
-    lanes are independent (budgets are per-candidate copies, :736). This is
-    the engine's analog of the reference evaluating selectVictimsOnNode in
-    parallel goroutines over candidate nodes (default_preemption.go:560-576).
+    `fits_many_fn(pod, [(node, remaining), ...]) -> [bool]` evaluates one
+    reprieve round of every candidate node in one call: each node advances
+    its reprieve loop in lockstep rounds. Per-lane semantics are identical to
+    select_victims_on_node — lanes are independent (budgets are
+    per-candidate copies, :736), as the reference's parallel goroutines over
+    candidate nodes are (default_preemption.go:560-576).
     """
     if pod.preemption_policy == "Never":
         return None  # PodEligibleToPreemptOthers (:231)

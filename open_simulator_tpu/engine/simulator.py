@@ -234,16 +234,6 @@ def _count_filter_failures(counts: np.ndarray) -> None:
             metrics.FILTER_FAILURE.inc(c, reason=FILTER_MESSAGES[f])
 
 
-# jitted preemption-probe programs keyed by (out-of-tree filter tuple,
-# packed-layout offsets) — shared across ALL Simulator instances so repeated
-# simulate() calls reuse compiled executables instead of retracing per
-# instance (see _device_fits_many). Bounded FIFO: a long-lived server sees
-# varying table layouts (and identity-keyed plugin closures) per request,
-# and an unbounded cache would pin every stale jit + its executables forever.
-_PROBE_JIT_CACHE: Dict[tuple, object] = {}
-_PROBE_JIT_CACHE_MAX = 32
-
-
 class Simulator:
     """Owns the device-resident cluster state for one simulation run."""
 
@@ -430,6 +420,15 @@ class Simulator:
         self._carry = None
         self._storage_takes: Dict[str, Tuple[np.ndarray, np.ndarray]] = {}
         self._preempted: List[PreemptedPod] = []
+        # DefaultPreemption's state: the bound-pod table the device victim
+        # search reads (built at the call's first search), the
+        # ids of pods evicted in the current pass (dropped from _bound when
+        # the pass ends) and each PDB's healthy count
+        self._victims = None
+        self._victims_stale = False
+        self._gone: set = set()
+        self._pdb_healthy: List[int] = []
+        self._preemptor_rows: Dict[tuple, object] = {}
         # PDBs ride along for DefaultPreemption's victim classification
         # (parity: the reference syncs PDBs into the fake cluster,
         # simulator.go:388-394, and the preemption plugin lists them).
@@ -450,6 +449,7 @@ class Simulator:
         from the resident device planes (no encode_nodes pass, no node-plane
         transfer); the per-request selector/port/anti counts are still built
         here — they depend on this request's registered selectors."""
+        self._victims = None
         res = self._resident
         if res is not None:
             reason = res.covers_reason(self.cluster.nodes, self._bound)
@@ -658,6 +658,8 @@ class Simulator:
                 np.asarray(dev_row).copy(),
             )
         self._bound.append((pod, pod.node_name))
+        if self._victims is not None:
+            self._victim_bound(pod, ni)
         # the single commit point for successful placements (failed
         # preemption retries roll back before ever reaching here)
         metrics.SCHEDULE_RESULT.inc(result="scheduled")
@@ -859,23 +861,17 @@ class Simulator:
         return f"0/{n_nodes} nodes are available: {detail}."
 
     # -- preemption (PostFilter) -------------------------------------------
-    # lanes per batched probe call: bounds vmap memory on huge clusters
-    # (each lane's run_filters is O(N) work) while keeping the jit cache to
-    # a handful of bucketed shapes
-    _PROBE_CHUNK = 256
-
-    def _pod_eviction_delta(self, v: Pod) -> np.ndarray:
-        """Additive packed-column delta of hypothetically evicting pod `v`
-        (reverse of its bind contributions; layout per _probe_offsets).
-        Computed once per pod per preemption pass (the encoder lookups —
-        match_vector/port_ids/anti_ids — are the expensive part)."""
+    def _eviction_delta(self, v: Pod, offs) -> np.ndarray:
+        """Additive packed-column delta (ops/preempt.PLANES layout, offsets
+        `offs`) of evicting pod `v` from its node: the reverse of its bind
+        contributions."""
         from ..ops.encode import match_vector, resource_scale
 
-        offs = self._probe_offsets()
-        d = np.zeros(offs["__total__"][1], np.float32)
+        d = np.zeros(offs[-1][2], np.float32)
+        at = {key: (s, e) for key, s, e in offs}
 
         def plane(key):
-            s, e = offs[key]
+            s, e = at[key]
             return d[s:e]
 
         free, sel = plane("free"), plane("sel")
@@ -912,205 +908,175 @@ class Simulator:
                 anti[aid] -= 1.0
         return d
 
-    # Packed probe-column layout: the nine carry planes a hypothetical
-    # eviction touches, flattened into ONE f32 vector per node column. One
-    # numpy slice builds a lane, one vector add applies a victim delta, one
-    # device_put ships a whole chunk — versus nine of each before
-    # (the 80k-dispatch hot spot that held preempt_tiered at ~12 pods/s).
-    _PROBE_PLANES = (
-        ("free", "free", True),        # (packed key, carry field, node-major)
-        ("sel", "sel_counts", False),
-        ("gpu", "gpu_free", True),
-        ("vg", "vg_free", True),
-        ("dev", "dev_free", True),
-        ("port_any", "port_any", False),
-        ("port_wild", "port_wild", False),
-        ("port_ipc", "port_ipc", False),
-        ("anti", "anti_counts", False),
-    )
+    def _pdb_row(self, v: Pod) -> np.ndarray:
+        """bool[max(B, 1)]: which PDBs match pod `v`."""
+        row = np.zeros(max(len(self._pdbs), 1), bool)
+        for i, pdb in enumerate(self._pdbs):
+            row[i] = pdb.matches(v)
+        return row
 
-    def _probe_offsets(self) -> Dict[str, Tuple[int, int]]:
-        """(start, end) of each plane inside the packed probe vector, from
-        the live carry's shapes (static at trace time)."""
-        offs: Dict[str, Tuple[int, int]] = {}
-        pos = 0
-        for key, field_name, node_major in self._PROBE_PLANES:
-            arr = getattr(self._carry, field_name)
-            n = arr.shape[1] if node_major else arr.shape[0]
-            offs[key] = (pos, pos + n)
-            pos += n
-        offs["__total__"] = (0, pos)
-        return offs
+    def _build_victims(self) -> None:
+        """The victim table of the live bound pods (ops/preempt.py), from
+        _bound: at the call's first search, again only if the carry's
+        layout changes or a node outgrows its slots. Pods equal in labels,
+        requests and ports share one delta computation."""
+        from ..ops.preempt import HostVictimTable, plane_offsets, table_rows_k
 
-    def _carry_host_packed(self) -> np.ndarray:
-        """f32[T, N] — every node's packed probe column, cached by carry
-        identity (any carry swap — bind, evict, reshard — builds a new
-        pytree and invalidates it). Host-side so lane construction is a
-        numpy slice, not an un-jitted device get."""
-        cached = getattr(self, "_carry_np", None)
-        if cached is None or cached[0] is not self._carry:
-            host = fetch(self._carry, "probe-carry")
-            planes = []
-            for key, field_name, node_major in self._PROBE_PLANES:
-                a = np.asarray(getattr(host, field_name), np.float32)
-                planes.append(a.T if node_major else a)
-            self._carry_np = (self._carry, np.concatenate(planes, axis=0))
-        return self._carry_np[1]
-
-    def _eviction_cols(
-        self, ni: int, on_node, keep_ids, delta_cache: Optional[dict] = None
-    ) -> np.ndarray:
-        """Packed node column state with ONLY the kept pods: the current
-        carry column plus the cached eviction delta of every pod not kept.
-        With the shared `delta_cache`, repeated reprieve rounds cost one
-        vector add per evicted pod instead of re-encoding it (linear, not
-        quadratic, in queue length)."""
-        cols = self._carry_host_packed()[:, ni].copy()
-        for v in on_node:
-            if id(v) in keep_ids:
+        offs = plane_offsets(self._carry)
+        index = self._name_index_map()
+        pdb_rows: Dict[tuple, np.ndarray] = {}
+        deltas: Dict[tuple, int] = {}
+        uniq: List[np.ndarray] = []
+        node_idx, prio, pods, which, pdbm = [], [], [], [], []
+        healthy = np.zeros(len(self._pdbs), np.int64)
+        for p, name in self._bound:
+            if id(p) in self._gone:
                 continue
-            if delta_cache is not None:
-                d = delta_cache.get(id(v))
-                if d is None:
-                    d = delta_cache[id(v)] = self._pod_eviction_delta(v)
-            else:
-                d = self._pod_eviction_delta(v)
-            cols += d
-        return cols
+            labels = (p.meta.namespace, tuple(sorted(p.meta.labels.items())))
+            pm = pdb_rows.get(labels)
+            if pm is None:
+                pm = pdb_rows[labels] = self._pdb_row(p)
+            if self._pdbs:
+                healthy += pm[: len(self._pdbs)]
+            ni = index.get(name)
+            if ni is None:
+                continue
+            own = (p.affinity.anti_required or p.gpu_mem_request() > 0
+                   or p.key in self._storage_takes)
+            key = None if own else (
+                labels, tuple(sorted(p.requests.items())),
+                tuple(map(tuple, p.host_ports)))
+            k = deltas.get(key) if key is not None else None
+            if k is None:
+                k = len(uniq)
+                uniq.append(self._eviction_delta(p, offs))
+                if key is not None:
+                    deltas[key] = k
+            node_idx.append(ni)
+            prio.append(p.priority)
+            pods.append(p)
+            which.append(k)
+            pdbm.append(pm)
+        node_idx = np.asarray(node_idx, np.int64)
+        counts = np.bincount(node_idx, minlength=self._table.n)
+        pods_alloc = np.asarray(self._table.alloc)[
+            : len(self.cluster.nodes), self.enc.resources.index("pods")]
+        vt = HostVictimTable(self._table.n, table_rows_k(pods_alloc, counts),
+                             offs, len(self._pdbs))
+        if pods:
+            vt.fill(node_idx, np.asarray(prio, np.int32), pods,
+                    np.stack(uniq)[np.asarray(which)], np.stack(pdbm))
+        self._victims, self._victims_stale = vt, False
+        self._pdb_healthy = [int(h) for h in healthy]
 
-    def _device_fits_many(self, bound_by_node):
-        """fits_many_fn for lane-parallel victim selection: evaluates ALL
-        candidate (node, remaining-set) states of one reprieve round in a
-        single vmapped device call (chunked at _PROBE_CHUNK lanes), running
-        the REAL filter kernel on each post-eviction column (parity:
-        selectVictimsOnNode's dry run of the filter plugins,
-        default_preemption.go:598-626, fanned out like its parallel
-        checkNode goroutines :560-576). Replaces one device round trip per
-        (node, victim-set) probe with one per round."""
+    def _victim_bound(self, pod: Pod, ni: int) -> None:
+        """A placement joins its node's row of the victim table."""
+        pm = self._pdb_row(pod)
+        for i in np.flatnonzero(pm[: len(self._pdbs)]):
+            self._pdb_healthy[i] += 1
+        vt = self._victims
+        if self._victims_stale:
+            return
+        if not vt.has_room(ni):
+            self._victims_stale = True  # rebuilt, with more slots, on use
+            return
+        vt.insert(ni, pod, pod.priority, self._eviction_delta(pod, vt.offs), pm)
+
+    def _table_put(self, tree):
+        """Host arrays to the device, node axis on the mesh when sharded."""
         import jax
-        import jax.numpy as jnp
 
-        from ..ops.encode import encode_pods
-        from ..ops.kernels import run_filters
+        if self.mesh is None:
+            return jax.device_put(tree)
+        from jax.sharding import NamedSharding, PartitionSpec
+
+        from ..parallel.mesh import NODE_AXIS
+
+        return jax.device_put(
+            tree, NamedSharding(self.mesh, PartitionSpec(NODE_AXIS)))
+
+    def _preemptor_row(self, pod: Pod):
+        """The pod's encoded row (host arrays), one encode per signature."""
+        import jax
+
+        from ..ops.encode import _pod_row_sig
         from ..ops.state import pod_rows_from_batch_host
 
-        # One jitted probe per (out-of-tree filter set, packed layout),
-        # cached at module level: a per-Simulator closure would retrace +
-        # recompile the whole vmapped filter family on EVERY simulate() call
-        # (each capacity probe, each server request, each bench repeat) — the
-        # compile tax that made preempt_tiered run at 11 pods/s warm. Lanes
-        # arrive as packed f32[lanes, T] vectors (see _PROBE_PLANES) and are
-        # unpacked with static offsets inside the jit.
-        offs = self._probe_offsets()
-        key = (
-            self._extra_filters,
-            tuple(sorted(offs.items())),
+        key = _pod_row_sig(pod)
+        row = self._preemptor_rows.get(key)
+        if row is None:
+            batch = encode_pods(self.enc, [pod])
+            row = jax.tree.map(lambda a: a[0], pod_rows_from_batch_host(batch))
+            self._preemptor_rows[key] = row
+        return row
+
+    def _search_victims(self, pod: Pod):
+        """dryRunPreemption -> CallExtenders -> SelectCandidate for one
+        preemptor (default_preemption.go:141-176): the device victim search
+        (ops/preempt.py), one host read. Preemption-supporting extenders get
+        every candidate node's victims. -> PreemptionResult or None."""
+        from ..ops.preempt import pdb_allowed_array, plane_offsets, victim_search
+        from .preemption import (
+            PreemptionResult,
+            call_preempt_extenders,
+            pick_one_node,
         )
-        probe = _PROBE_JIT_CACHE.get(key)
-        metrics.COMPILE_CACHE.inc(
-            event="hit" if probe is not None else "miss"
+
+        offs = plane_offsets(self._carry)
+        if (self._victims is None or self._victims_stale
+                or self._victims.offs != offs):
+            self._build_victims()
+        vt = self._victims
+        prof = self._profiles.get(pod.scheduler_name)
+        fo = prof[1] if prof is not None else None
+        fo = np.ones(NUM_FILTERS, bool) if fo is None else np.asarray(fo)
+        to_extenders = any(
+            e.supports_preemption and e.is_interested(pod)
+            for e in self._extenders
         )
-        if probe is None:
-            extra_filters = self._extra_filters
-            o = dict(offs)
-
-            def pl(col, k):
-                s, e = o[k]
-                return col[s:e]
-
-            @jax.jit
-            def probe_many(ns, carry, row, nis, cols, filter_on):
-                def one(ni, col):
-                    carry2 = carry._replace(
-                        free=carry.free.at[ni].set(pl(col, "free")),
-                        sel_counts=carry.sel_counts.at[:, ni].set(pl(col, "sel")),
-                        gpu_free=carry.gpu_free.at[ni].set(pl(col, "gpu")),
-                        vg_free=carry.vg_free.at[ni].set(pl(col, "vg")),
-                        dev_free=carry.dev_free.at[ni].set(pl(col, "dev")),
-                        port_any=carry.port_any.at[:, ni].set(pl(col, "port_any")),
-                        port_wild=carry.port_wild.at[:, ni].set(pl(col, "port_wild")),
-                        port_ipc=carry.port_ipc.at[:, ni].set(pl(col, "port_ipc")),
-                        anti_counts=carry.anti_counts.at[:, ni].set(pl(col, "anti")),
-                    )
-                    # same filter set the pod's profile schedules with (mask
-                    # + out-of-tree plugins) — a disabled filter must not
-                    # veto a node here either
-                    mask, _ = run_filters(
-                        ns, carry2, row, filter_on, extra_filters
-                    )
-                    return mask[ni]
-
-                return jax.vmap(one)(nis, cols)
-
-            while len(_PROBE_JIT_CACHE) >= _PROBE_JIT_CACHE_MAX:
-                _PROBE_JIT_CACHE.pop(next(iter(_PROBE_JIT_CACHE)))
-            probe = _PROBE_JIT_CACHE[key] = probe_many
-
-        row_cache: Dict[str, object] = {}
-        delta_cache: dict = {}
-        name_index = self._name_index_map()
-
-        def fits_many(pod: Pod, items) -> List[bool]:
-            if not items:
-                return []
-            prof = self._profiles.get(pod.scheduler_name)
-            fo = prof[1] if prof is not None else None
-            fo = (
-                jnp.ones(len(FILTER_MESSAGES), bool)
-                if fo is None
-                else jnp.asarray(fo)
+        whole = bool(self._extra_filters)
+        path = ("extenders" if to_extenders
+                else "whole_cluster" if whole else "device")
+        metrics.VICTIM_SEARCH.inc(path=path)
+        with span("victim-search", path=path):
+            got = fetch(
+                victim_search(
+                    self._ns, self._carry, vt.device(self._table_put),
+                    self._preemptor_row(pod), np.int32(pod.priority), fo,
+                    pdb_allowed_array(self._pdbs, self._pdb_healthy),
+                    offs=offs, extra_filters=self._extra_filters,
+                    whole_cluster=whole, all_nodes=to_extenders,
+                ),
+                "victim-search",
             )
-            row = row_cache.get(pod.key)
-            if row is None:
-                batch = encode_pods(self.enc, [pod])
-                # host rows: slicing device arrays is ~40 un-jitted
-                # gets per preemptor
-                row = jax.tree.map(
-                    lambda a: a[0], pod_rows_from_batch_host(batch)
-                )
-                row_cache[pod.key] = row
-            out: List[bool] = []
-            for start in range(0, len(items), self._PROBE_CHUNK):
-                chunk = items[start : start + self._PROBE_CHUNK]
-                nis = np.array(
-                    [name_index[node.name] for node, _ in chunk], np.int32
-                )
-                col_list = [
-                    self._eviction_cols(
-                        name_index[node.name],
-                        bound_by_node.get(node.name, []),
-                        {id(p) for p in remaining},
-                        delta_cache,
-                    )
-                    for node, remaining in chunk
-                ]
-                stacked = np.stack(col_list)   # f32[lanes, T] packed columns
-                # pad the lane axis to a power-of-FOUR bucket (4/16/64/256):
-                # each distinct lane count would otherwise compile its own
-                # vmapped run_filters executable, and the compiles dominate
-                # preemption wall time on cold caches (bench preempt_tiered)
-                c = len(chunk)
-                c_pad = 4
-                while c_pad < c:
-                    c_pad *= 4
-                if c_pad != c:
-                    nis = np.concatenate([nis, np.repeat(nis[:1], c_pad - c)])
-                    stacked = np.concatenate(
-                        [stacked, np.repeat(stacked[:1], c_pad - c, axis=0)]
-                    )
-                # dispatch through the locally-resolved probe: a fits_many
-                # closure must keep the jit whose offsets match the columns
-                # IT builds, even if a later rebuild resolved a newer one
-                res = probe(
-                    self._ns, self._carry, row, jnp.asarray(nis),
-                    jnp.asarray(stacked), fo,
-                )
-                out.extend(
-                    bool(b) for b in fetch(res, "preempt-probe")[:c]
-                )
-            return out
-
-        return fits_many
+        names = self._table.names
+        if not to_extenders:
+            ni = int(got.node)
+            if ni < 0:
+                return None
+            return PreemptionResult(
+                node=names[ni],
+                victims=vt.victims_in_order(ni, got.qidx, got.victims),
+                num_pdb_violations=int(got.n_viol),
+            )
+        candidates = [
+            PreemptionResult(
+                node=names[ni],
+                victims=vt.victims_in_order(ni, got.qidx[ni], got.victims[ni]),
+                num_pdb_violations=int(got.n_viol[ni]),
+            )
+            for ni in np.flatnonzero(got.cand)
+        ]
+        bound_by_node: Dict[str, List[Pod]] = {}
+        for p, name in self._bound:
+            if id(p) not in self._gone:
+                bound_by_node.setdefault(name, []).append(p)
+        candidates = call_preempt_extenders(
+            self._extenders, pod, candidates, bound_by_node, self.cluster.nodes
+        )
+        # an extender may empty a node's victim list while keeping the node:
+        # the pod only reached preemption because it failed, so drop those
+        return pick_one_node([c for c in candidates if c.victims])
 
     def _try_preemptions(
         self, failed: List[UnscheduledPod]
@@ -1128,141 +1094,118 @@ class Simulator:
     def _preempt_failed(
         self, failed: List[UnscheduledPod]
     ) -> List[UnscheduledPod]:
-        from .extenders import ExtenderError
-        from .preemption import try_preempt
-
         still_failed: List[UnscheduledPod] = []
-        bound_by_node: Optional[Dict[str, List[Pod]]] = None
-        fits_many_fn = None
         for u in failed:
             pod = u.pod
             if pod.priority <= 0:
                 still_failed.append(u)
-                continue
-            if bound_by_node is None:
-                bound_by_node = {}
-                for p, node_name in self._bound:
-                    bound_by_node.setdefault(node_name, []).append(p)
-                fits_many_fn = self._device_fits_many(bound_by_node)
-            try:
-                res = try_preempt(
-                    pod, self.cluster.nodes, bound_by_node, self._pdbs,
-                    fits_many_fn=fits_many_fn, extenders=self._extenders,
-                )
-            except ExtenderError as e:
-                # a non-ignorable extender failed ProcessPreemption: the
-                # reference aborts this pod's preemption with the error
-                # (default_preemption.go:373-374) — the pod stays failed
-                # with the extender's message appended
-                metrics.PREEMPTION_ATTEMPTS.inc(outcome="extender_error")
-                still_failed.append(
-                    UnscheduledPod(pod=pod, reason=f"{u.reason}; {e}")
-                )
-                continue
-            if res is None or not res.victims:
+            elif pod.preemption_policy == "Never":
+                # PodEligibleToPreemptOthers (default_preemption.go:231)
                 metrics.PREEMPTION_ATTEMPTS.inc(outcome="no_candidates")
                 still_failed.append(u)
-                continue
-            # The host-side victim model covers resources only; the device
-            # retry additionally enforces spread/affinity/storage/GPU. Snapshot
-            # everything eviction touches so a failed retry rolls back instead
-            # of leaving pods evicted for nothing. A borrowed victim is never
-            # reset (_evict resets its copy), so it has no fields to restore.
-            snapshot = (
-                self._carry,
-                list(self._bound),
-                dict(self._storage_takes),
-                len(self._preempted),
-                self._snapshot_bindings(
-                    [v for v in res.victims if id(v) not in self._borrowed]
-                ),
-            )
-            self._evict(res.victims, res.node, by=pod.key)
-            # Reschedule the preemptor now that room exists. The reference
-            # nominates the node and requeues; the retried pod normally lands
-            # there but isn't pinned — same here (scores decide).
-            retry_failed = self._schedule_batch_host([pod])
-            if retry_failed:
-                carry, bound_list, takes, n_pre, fields = snapshot
-                self._carry = carry
-                self._bound = bound_list
-                self._storage_takes = takes
-                del self._preempted[n_pre:]
-                self._restore_bindings(fields)
-                metrics.PREEMPTION_ATTEMPTS.inc(outcome="retry_failed")
-                still_failed.extend(retry_failed)
             else:
-                # preemption committed: victims stay evicted — count them
-                # here, NOT in _evict (the rollback path above un-evicts)
-                metrics.PREEMPTION_ATTEMPTS.inc(outcome="succeeded")
-                metrics.SCHEDULE_RESULT.inc(
-                    len(res.victims), result="preempted"
-                )
-                bound_by_node = None  # placements changed; rebuild lazily
+                with span("preempt", pod=pod.key):
+                    still_failed.extend(self._preempt_one(u))
+        if self._gone:  # evicted pods leave _bound once, when the pass ends
+            self._bound = [
+                (p, n) for p, n in self._bound if id(p) not in self._gone
+            ]
+            self._gone.clear()
         return still_failed
 
-    def _evict(self, victims: List[Pod], node_name: str, by: str) -> None:
-        """Remove victims from a node and reverse their carry contributions.
-        A borrowed victim (the caller's pre-bound pod) is recorded and reset
-        as the engine's own copy; the caller's object stays as it was."""
-        victim_keys = {id(v) for v in victims}
-        self._bound = [
-            (p, n) for p, n in self._bound if id(p) not in victim_keys
-        ]
-        ni = self._table.names.index(node_name)
-        host = fetch(self._carry, "evict")
-        free = np.array(host.free)
-        sel = np.array(host.sel_counts)
-        gpu = np.array(host.gpu_free)
-        vg = np.array(host.vg_free)
-        dev = np.array(host.dev_free)
-        port_any = np.array(host.port_any)
-        port_wild = np.array(host.port_wild)
-        port_ipc = np.array(host.port_ipc)
-        anti = np.array(host.anti_counts)
-        from ..ops.encode import resource_scale
+    def _preempt_one(self, u: UnscheduledPod) -> List[UnscheduledPod]:
+        """One preemptor: search, evict, reschedule; roll the eviction back
+        if the retry fails. -> the pod's failures (none on success)."""
+        from .extenders import ExtenderError
 
-        from ..ops.encode import match_vector
+        pod = u.pod
+        try:
+            res = self._search_victims(pod)
+        except ExtenderError as e:
+            # a non-ignorable extender failed ProcessPreemption: the
+            # reference aborts this pod's preemption with the error
+            # (default_preemption.go:373-374) — the pod stays failed
+            # with the extender's message appended
+            metrics.PREEMPTION_ATTEMPTS.inc(outcome="extender_error")
+            return [UnscheduledPod(pod=pod, reason=f"{u.reason}; {e}")]
+        if res is None or not res.victims:
+            metrics.PREEMPTION_ATTEMPTS.inc(outcome="no_candidates")
+            return [u]
+        # The retry schedules with every filter; snapshot what eviction
+        # touches so a failed retry rolls back instead of leaving pods
+        # evicted for nothing. A borrowed victim is never reset (_evict
+        # resets its copy), so it has no fields to restore.
+        ni = self._name_index_map()[res.node]
+        snapshot = (
+            self._carry,
+            len(self._bound),
+            len(self._preempted),
+            self._snapshot_bindings(
+                [v for v in res.victims if id(v) not in self._borrowed]
+            ),
+            self._victims.snapshot([ni]),
+            list(self._pdb_healthy),
+        )
+        with span("evict", victims=len(res.victims)):
+            takes = self._evict(res.victims, ni, by=pod.key)
+        # Reschedule the preemptor now that room exists. The reference
+        # nominates the node and requeues; the retried pod normally lands
+        # there but isn't pinned — same here (scores decide).
+        retry_failed = self._schedule_batch_host([pod])
+        if retry_failed:
+            carry, n_bound, n_pre, fields, rows, healthy = snapshot
+            self._carry = carry
+            del self._bound[n_bound:]
+            self._gone.difference_update(id(v) for v in res.victims)
+            self._storage_takes.update(takes)
+            del self._preempted[n_pre:]
+            self._restore_bindings(fields)
+            self._victims.restore(rows)
+            self._pdb_healthy = healthy
+            metrics.PREEMPTION_ATTEMPTS.inc(outcome="retry_failed")
+            return retry_failed
+        # preemption committed: victims stay evicted — count them here, NOT
+        # in _evict (the rollback path above un-evicts)
+        metrics.PREEMPTION_ATTEMPTS.inc(outcome="succeeded")
+        metrics.SCHEDULE_RESULT.inc(len(res.victims), result="preempted")
+        return []
 
+    def _evict(
+        self, victims: List[Pod], ni: int, by: str
+    ) -> Dict[str, Tuple[np.ndarray, np.ndarray]]:
+        """Remove victims from node `ni`: their slots leave the victim table
+        and their deltas are added to the node's carry column on the device,
+        one victim after another. A borrowed victim (the caller's pre-bound
+        pod) is recorded and reset as the engine's own copy; the caller's
+        object stays as it was. -> the storage takes removed with them."""
+        from ..ops.preempt import apply_evictions
+
+        vt = self._victims
+        deltas = vt.take(ni, victims)
+        rows = np.zeros(
+            (max(8, 1 << (len(victims) - 1).bit_length()), deltas.shape[1]),
+            np.float32,
+        )
+        rows[: len(victims)] = deltas
+        self._carry = apply_evictions(
+            self._carry, np.int32(ni), rows, offs=vt.offs
+        )
+        self._reshard()
+        node_name = self._table.names[ni]
+        takes: Dict[str, Tuple[np.ndarray, np.ndarray]] = {}
         for v in victims:
-            for res, q in v.requests.items():
-                r = self.enc.resources.index(res) if res in self.enc.resources else -1
-                if r >= 0:
-                    free[ni, r] += q / resource_scale(res)
-            free[ni, self.enc.resources.index("pods")] += 1.0
-            vec = match_vector(self.enc, v)
-            m = min(vec.shape[0], sel.shape[0])
-            sel[:m, ni] -= vec[:m]
-            mem = v.gpu_mem_request()
-            if mem > 0:
-                for d in v.gpu_index_ids():
-                    if 0 <= d < gpu.shape[1]:
-                        gpu[ni, d] += np.float32(mem / float(1 << 20))
-            takes = self._storage_takes.pop(v.key, None)
-            if takes is not None:
-                vg[ni, : takes[0].shape[0]] += takes[0]
-                dev[ni, : takes[1].shape[0]] += takes[1]
-            for pid, wild, ipid in self.enc.port_ids(v):
-                if pid < port_any.shape[0]:
-                    port_any[pid, ni] -= 1.0
-                    if wild:
-                        port_wild[pid, ni] -= 1.0
-                if not wild and ipid < port_ipc.shape[0]:
-                    port_ipc[ipid, ni] -= 1.0
-            for aid in self.enc.anti_ids(v):
-                if aid < anti.shape[0]:
-                    anti[aid, ni] -= 1.0
+            self._gone.add(id(v))
+            t = self._storage_takes.pop(v.key, None)
+            if t is not None:
+                takes[v.key] = t
+            for i in np.flatnonzero(self._pdb_row(v)[: len(self._pdbs)]):
+                self._pdb_healthy[i] -= 1
             if id(v) in self._borrowed:
                 v = copy.deepcopy(v)
                 metrics.BOUND_POD_COPIES.inc()
             self._reset_bindings([v])
             self._preempted.append(PreemptedPod(pod=v, node=node_name, by=by))
-        self._carry = self._carry._replace(
-            free=free, sel_counts=sel, gpu_free=gpu, vg_free=vg, dev_free=dev,
-            port_any=port_any, port_wild=port_wild, port_ipc=port_ipc,
-            anti_counts=anti,
-        )
-        self._reshard()
+        return takes
 
     # The engine's FULL mutation surface on a pod is node_name / phase / the
     # gpu-index annotation (placement at _bind_placed, eviction at _evict) —

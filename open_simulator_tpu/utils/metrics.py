@@ -12,7 +12,8 @@ Parity table (ours -> kube-scheduler):
     osim_pod_scheduling_attempts_total    -> scheduler_pod_scheduling_attempts
     osim_schedule_result_total{result=}   -> scheduler_schedule_attempts_total{result=}
     osim_filter_failure_total{reason=}    -> (per-plugin UnschedulableAndUnresolvable counts)
-    osim_compile_cache_total{event=}      -> (no analogue: XLA jit-probe cache hit/miss)
+    osim_compile_cache_total{event=}      -> (no analogue: XLA compile requests, cache hits)
+    osim_victim_search_total{path=}       -> (no analogue: DefaultPreemption searches by path)
     osim_encode_duration_seconds          -> (no analogue: cluster/pod -> device-array encode)
 
 Exposure paths:
@@ -408,7 +409,8 @@ SCHEDULE_RESULT = REGISTRY.counter(
 )
 COMPILE_CACHE = REGISTRY.counter(
     "osim_compile_cache_total",
-    "Device-probe jit cache lookups (miss = new XLA compile).",
+    "XLA compile requests (backend_compile) and those the persistent "
+    "cache served (persistent_hit).",
     labelnames=("event",),
 )
 EXPAND_CACHE = REGISTRY.counter(
@@ -431,6 +433,14 @@ PREEMPTION_ATTEMPTS = REGISTRY.counter(
     "osim_preemption_attempts_total",
     "Preemption attempts for unscheduled pods, by outcome.",
     labelnames=("outcome",),
+)
+VICTIM_SEARCH = REGISTRY.counter(
+    "osim_victim_search_total",
+    "DefaultPreemption victim searches, by path: device (every filter "
+    "evaluated per node on the device), whole_cluster (out-of-tree filters: "
+    "each node's lane runs every filter over the whole cluster), extenders "
+    "(every candidate's victims go to preemption-supporting extenders).",
+    labelnames=("path",),
 )
 BOUND_POD_COPIES = REGISTRY.counter(
     "osim_bound_pod_copies_total",
