@@ -429,6 +429,8 @@ class Simulator:
         self._gone: set = set()
         self._pdb_healthy: List[int] = []
         self._preemptor_rows: Dict[tuple, object] = {}
+        # (node, padded delta rows) of the last eviction, for _retry
+        self._evict_rows: Optional[Tuple[int, np.ndarray]] = None
         # PDBs ride along for DefaultPreemption's victim classification
         # (parity: the reference syncs PDBs into the fake cluster,
         # simulator.go:388-394, and the preemption plugin lists them).
@@ -996,7 +998,9 @@ class Simulator:
             tree, NamedSharding(self.mesh, PartitionSpec(NODE_AXIS)))
 
     def _preemptor_row(self, pod: Pod):
-        """The pod's encoded row (host arrays), one encode per signature."""
+        """The pod's encoded row on the device (replicated on a mesh), one
+        encode and one transfer per signature; the victim search and the
+        retry read it."""
         import jax
 
         from ..ops.encode import _pod_row_sig
@@ -1007,6 +1011,12 @@ class Simulator:
         if row is None:
             batch = encode_pods(self.enc, [pod])
             row = jax.tree.map(lambda a: a[0], pod_rows_from_batch_host(batch))
+            if self.mesh is None:
+                row = jax.device_put(row)
+            else:
+                from ..parallel.mesh import replicated
+
+                row = jax.device_put(row, replicated(self.mesh, row))
             self._preemptor_rows[key] = row
         return row
 
@@ -1114,8 +1124,8 @@ class Simulator:
         return still_failed
 
     def _preempt_one(self, u: UnscheduledPod) -> List[UnscheduledPod]:
-        """One preemptor: search, evict, reschedule; roll the eviction back
-        if the retry fails. -> the pod's failures (none on success)."""
+        """One preemptor: search, evict, retry; roll the eviction back if
+        the retry fails. -> the pod's failures (none on success)."""
         from .extenders import ExtenderError
 
         pod = u.pod
@@ -1148,10 +1158,7 @@ class Simulator:
         )
         with span("evict", victims=len(res.victims)):
             takes = self._evict(res.victims, ni, by=pod.key)
-        # Reschedule the preemptor now that room exists. The reference
-        # nominates the node and requeues; the retried pod normally lands
-        # there but isn't pinned — same here (scores decide).
-        retry_failed = self._schedule_batch_host([pod])
+        retry_failed = self._retry(pod)
         if retry_failed:
             carry, n_bound, n_pre, fields, rows, healthy = snapshot
             self._carry = carry
@@ -1173,13 +1180,13 @@ class Simulator:
     def _evict(
         self, victims: List[Pod], ni: int, by: str
     ) -> Dict[str, Tuple[np.ndarray, np.ndarray]]:
-        """Remove victims from node `ni`: their slots leave the victim table
-        and their deltas are added to the node's carry column on the device,
-        one victim after another. A borrowed victim (the caller's pre-bound
-        pod) is recorded and reset as the engine's own copy; the caller's
-        object stays as it was. -> the storage takes removed with them."""
-        from ..ops.preempt import apply_evictions
-
+        """Remove victims from node `ni` on the host: their slots leave the
+        victim table, and their deltas (zero rows pad them to a power of two
+        of at least 8) are left in `_evict_rows` for `_retry` to add to the
+        node's carry column on the device. A borrowed victim (the caller's
+        pre-bound pod) is recorded and reset as the engine's own copy; the
+        caller's object stays as it was. -> the storage takes removed with
+        them."""
         vt = self._victims
         deltas = vt.take(ni, victims)
         rows = np.zeros(
@@ -1187,10 +1194,7 @@ class Simulator:
             np.float32,
         )
         rows[: len(victims)] = deltas
-        self._carry = apply_evictions(
-            self._carry, np.int32(ni), rows, offs=vt.offs
-        )
-        self._reshard()
+        self._evict_rows = (ni, rows)
         node_name = self._table.names[ni]
         takes: Dict[str, Tuple[np.ndarray, np.ndarray]] = {}
         for v in victims:
@@ -1206,6 +1210,51 @@ class Simulator:
             self._reset_bindings([v])
             self._preempted.append(PreemptedPod(pod=v, node=node_name, by=by))
         return takes
+
+    def _retry(self, pod: Pod) -> List[UnscheduledPod]:
+        """Reschedule a preemptor after `_evict`, over every node: the
+        reference nominates the node and requeues, and the retried pod
+        normally lands there but is not pinned (scores decide). With a
+        configured profile and no interested extender, the eviction and one
+        scheduling step run as one device program (ops/preempt.retry_one)
+        with one host read; otherwise the eviction runs alone and the pod
+        goes through `_schedule_batch_host` (the extender per-pod path, the
+        missing-profile failure). -> the pod's failures (none on success)."""
+        from ..ops.preempt import apply_evictions, retry_one
+
+        ni, rows = self._evict_rows
+        self._evict_rows = None
+        offs = self._victims.offs
+        prof = self._profiles.get(pod.scheduler_name)
+        if prof is None or any(e.is_interested(pod) for e in self._extenders):
+            metrics.PREEMPTION_RETRY.inc(path="batch")
+            self._carry = apply_evictions(
+                self._carry, np.int32(ni), rows, offs=offs
+            )
+            self._reshard()
+            return self._schedule_batch_host([pod])
+        metrics.PREEMPTION_RETRY.inc(path="device")
+        weights, fo = prof
+        fo = np.ones(NUM_FILTERS, bool) if fo is None else np.asarray(fo)
+        with span("retry"):
+            # the search just read this state: every pod of the call was
+            # registered before it was built, so it needs no align_carry
+            self._carry, out = retry_one(
+                self._ns, self._carry, np.int32(ni), rows,
+                self._preemptor_row(pod), weights, fo, offs=offs,
+                extra_filters=self._extra_filters,
+                extra_scores=self._extra_scores,
+            )
+            self._reshard()
+            node, reasons, take, vg_take, dev_take = fetch(out, "retry")
+            metrics.SCHEDULING_ATTEMPTS.inc()
+            if node >= 0:
+                self._bind_placed(pod, int(node), take, vg_take, dev_take)
+                return []
+            _count_filter_failures(reasons)
+            return [UnscheduledPod(
+                pod, _reason_string(len(self.cluster.nodes), reasons)
+            )]
 
     # The engine's FULL mutation surface on a pod is node_name / phase / the
     # gpu-index annotation (placement at _bind_placed, eviction at _evict) —

@@ -113,20 +113,19 @@ def _static_parts(ns: NodeStatic, pod: PodRow, weights: jnp.ndarray, filter_on=N
     return static_ok, static_first_fail, static_scores, na_ok
 
 
-def schedule_group(
+def group_step(
     ns: NodeStatic,
-    carry: Carry,
     pod: PodRow,
-    group_size: int,
-    valid_count: jnp.ndarray,
     weights: jnp.ndarray,
     filter_on=None,
     extra_filters=(),
     extra_scores=(),
 ):
-    """Schedule `group_size` copies of one pod spec; only the first
-    `valid_count` steps commit. Returns (carry, nodes i32[G], reasons i32[G,F]).
-    """
+    """One scheduling step of `pod` over every node, its static parts
+    hoisted: -> step(carry, active) -> (carry, (node i32, reasons i32[F],
+    gpu_take i32[G], vg_take f32[V], dev_take f32[DV])). An inactive step
+    commits nothing. schedule_group scans it; a preemptor's retry
+    (ops/preempt.retry_one) runs it once."""
     static_ok, static_ff, static_scores, na_ok = _static_parts(
         ns, pod, weights, filter_on
     )
@@ -134,8 +133,7 @@ def schedule_group(
         jnp.ones(NUM_FILTERS, bool) if filter_on is None else filter_on
     )
 
-    def step(c: Carry, i):
-        active = i < valid_count
+    def step(c: Carry, active):
         port_ok = ports_mask(c, pod) | ~fo[F_NODE_PORTS]
         res_fail = resource_fail(ns, c, pod) & fo[F_RESOURCES]
         spread_ok = spread_mask(ns, c, pod, na_ok) | ~fo[F_SPREAD]
@@ -238,7 +236,27 @@ def schedule_group(
             dev_take_sel,
         )
 
-    return jax.lax.scan(step, carry, jnp.arange(group_size))
+    return step
+
+
+def schedule_group(
+    ns: NodeStatic,
+    carry: Carry,
+    pod: PodRow,
+    group_size: int,
+    valid_count: jnp.ndarray,
+    weights: jnp.ndarray,
+    filter_on=None,
+    extra_filters=(),
+    extra_scores=(),
+):
+    """Schedule `group_size` copies of one pod spec; only the first
+    `valid_count` steps commit. Returns (carry, nodes i32[G], reasons i32[G,F]).
+    """
+    step = group_step(ns, pod, weights, filter_on, extra_filters, extra_scores)
+    return jax.lax.scan(
+        lambda c, i: step(c, i < valid_count), carry, jnp.arange(group_size)
+    )
 
 
 _group_jit = jax.jit(

@@ -32,6 +32,10 @@ carry with only that lane's column changed:
 The host reads one small pytree per preemptor: the chosen node, its queue
 order and which queue positions are victims (or, for preemption-supporting
 extenders, every node's).
+
+`retry_one` then reschedules the preemptor as one program: the victims'
+deltas added to the chosen node's column, and one scheduling step over every
+node (`grouped.group_step`), read by the host once.
 """
 
 from __future__ import annotations
@@ -63,6 +67,7 @@ from .kernels import (
     run_filters,
     taint_mask,
 )
+from .grouped import group_step
 
 #: the carry planes an eviction changes, in packed order:
 #: (packed key, carry field, node-major)
@@ -152,12 +157,40 @@ def _column(carry: Carry, ni) -> jnp.ndarray:
          for _, f, nm in PLANES])
 
 
-@partial(jax.jit, static_argnames=("offs",))
-def apply_evictions(carry: Carry, ni, deltas: jnp.ndarray, offs: Offsets) -> Carry:
-    """Add the victims' deltas f32[V,T] to node `ni`'s column, one victim
-    after another (zero rows pad V); the carry never leaves the device."""
+def _evicted(carry: Carry, ni, deltas: jnp.ndarray, offs: Offsets) -> Carry:
+    """`carry` with the victims' deltas f32[V,T] added to node `ni`'s column,
+    one victim after another (zero rows pad V)."""
     col, _ = jax.lax.scan(lambda c, d: (c + d, None), _column(carry, ni), deltas)
     return _set_column(carry, ni, col, offs)
+
+
+@partial(jax.jit, static_argnames=("offs",))
+def apply_evictions(carry: Carry, ni, deltas: jnp.ndarray, offs: Offsets) -> Carry:
+    """The eviction alone; the carry never leaves the device."""
+    return _evicted(carry, ni, deltas, offs)
+
+
+@partial(jax.jit, static_argnames=("offs", "extra_filters", "extra_scores"))
+def retry_one(
+    ns: NodeStatic,
+    carry: Carry,
+    ni,
+    deltas: jnp.ndarray,
+    row: PodRow,
+    weights,
+    filter_on,
+    *,
+    offs: Offsets,
+    extra_filters=(),
+    extra_scores=(),
+):
+    """A preemptor's retry as one program: the eviction (`apply_evictions`)
+    then one scheduling step of `row` over every node, the step
+    `grouped.schedule_group` scans. -> (carry, (node, reasons, gpu_take,
+    vg_take, dev_take)); the step chooses the node by score, so the
+    preemptor is not pinned to `ni`."""
+    step = group_step(ns, row, weights, filter_on, extra_filters, extra_scores)
+    return step(_evicted(carry, ni, deltas, offs), jnp.bool_(True))
 
 
 @partial(jax.jit, donate_argnums=(0,))

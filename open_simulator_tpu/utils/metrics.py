@@ -442,6 +442,14 @@ VICTIM_SEARCH = REGISTRY.counter(
     "(every candidate's victims go to preemption-supporting extenders).",
     labelnames=("path",),
 )
+PREEMPTION_RETRY = REGISTRY.counter(
+    "osim_preemption_retry_total",
+    "Preemptors rescheduled after their eviction, by path: device (the "
+    "eviction and one scheduling step as one device program), batch (the "
+    "eviction, then a one-pod batch through the commit engine: an extender "
+    "is interested in the pod, or its profile is missing).",
+    labelnames=("path",),
+)
 BOUND_POD_COPIES = REGISTRY.counter(
     "osim_bound_pod_copies_total",
     "Pre-bound cluster pods copied because preemption evicted them, "

@@ -9,6 +9,7 @@ PodEligibleToPreemptOthers :231).
 import copy
 
 import numpy as np
+import pytest
 
 from open_simulator_tpu.core.objects import LabelSelector, Node, Pod
 from open_simulator_tpu.engine.preemption import (
@@ -26,6 +27,7 @@ from open_simulator_tpu.engine.simulator import (
     UnscheduledPod,
     simulate,
 )
+from open_simulator_tpu.models.profiles import ExtenderConfig
 from open_simulator_tpu.utils import metrics
 
 
@@ -386,9 +388,17 @@ def test_preemption_copies_borrowed_victim_and_leaves_caller_pod():
     assert victim == snapshot
 
 
-def test_preemption_rollback_leaves_borrowed_victim_bound(monkeypatch):
+@pytest.mark.parametrize("path", ("device", "batch"))
+def test_preemption_rollback_leaves_borrowed_victim_bound(
+    path, monkeypatch, stub_factory
+):
     """The retry after eviction fails: the eviction rolls back, and the
-    caller's pod is still bound, listed on its node and not preempted."""
+    caller's pod is still bound, listed on its node and not preempted. The
+    failure is forced where each retry path reschedules: the device program
+    `retry_one`, or `_schedule_batch_host` when an extender is interested in
+    the preemptor."""
+    from open_simulator_tpu.ops import preempt
+
     cluster, apps = _borrowed_victim_case()
     victim = cluster.pods[0]
     snapshot = copy.deepcopy(victim)
@@ -403,8 +413,24 @@ def test_preemption_rollback_leaves_borrowed_victim_bound(monkeypatch):
         return real(self, pods)
 
     monkeypatch.setattr(Simulator, "_schedule_batch_host", fail_retry)
+    kw = {}
+    if path == "device":
+        real_retry = preempt.retry_one
+
+        def fail_on_device(*args, **kwargs):
+            seen.append(args)
+            carry, (_, *outs) = real_retry(*args, **kwargs)
+            return carry, (np.int32(-1), *outs)
+
+        monkeypatch.setattr(preempt, "retry_one", fail_on_device)
+    else:
+        stub = stub_factory({})
+        kw["extenders"] = [
+            ExtenderConfig(url_prefix=stub.url, filter_verb="filter")
+        ]
+    retries = metrics.PREEMPTION_RETRY.value(path=path)
     copies = metrics.BOUND_POD_COPIES.value()
-    result = simulate(cluster, apps)
+    result = simulate(cluster, apps, **kw)
     assert len(seen) == 2
     assert victim == snapshot
     assert not result.preempted
@@ -412,6 +438,7 @@ def test_preemption_rollback_leaves_borrowed_victim_bound(monkeypatch):
     assert [p for st in result.node_status for p in st.pods] == [victim]
     assert result.node_status[0].pods[0] is victim
     assert metrics.BOUND_POD_COPIES.value() == copies + 1
+    assert metrics.PREEMPTION_RETRY.value(path=path) == retries + 1
 
 
 def test_lane_parallel_driver_matches_sequential():

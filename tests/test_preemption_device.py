@@ -1,7 +1,8 @@
 """DefaultPreemption's device victim search (ops/preempt.py) against the host
 driver of engine/preemption.py over the numpy filter oracle, against the
 answers the lane-by-lane device probe it replaced gave, and its cost in
-host reads per preemptor."""
+host reads per preemptor; the preemptor's retry program against the
+eviction followed by the grouped scan's first step."""
 
 import copy
 import hashlib
@@ -9,6 +10,8 @@ import json
 import os
 
 import jax
+import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from open_simulator_tpu.analysis import oracle
@@ -24,7 +27,7 @@ from open_simulator_tpu.engine.simulator import (
     simulate,
 )
 from open_simulator_tpu.models.profiles import ExtenderConfig
-from open_simulator_tpu.ops import kernels
+from open_simulator_tpu.ops import grouped, kernels, preempt
 from open_simulator_tpu.ops.encode import (
     aggregate_gpu_usage,
     aggregate_usage,
@@ -251,16 +254,17 @@ def test_whole_cluster_path_agrees(kind):
     assert whole == plain
 
 
+def _count(span, name):
+    return (span["name"] == name) + sum(_count(c, name) for c in span.get("children", []))
+
+
 def _syncs_per_preemptor(root):
     """host-sync spans under each `preempt` span of a finished root."""
     out = []
 
-    def count(span):
-        return (span["name"] == "host-sync") + sum(count(c) for c in span.get("children", []))
-
     def walk(span):
         if span["name"] == "preempt":
-            out.append(count(span))
+            out.append(_count(span, "host-sync"))
         for c in span.get("children", []):
             walk(c)
 
@@ -270,16 +274,94 @@ def _syncs_per_preemptor(root):
 
 @pytest.mark.parametrize("n_nodes", (40, 400))
 def test_host_syncs_per_preemptor_do_not_grow_with_nodes(n_nodes):
-    """The search is one host read; the eviction none; the retry's one-pod
-    schedule three (align-carry, batch-entry, grouped-chunk). At most four
-    per preemptor, at 40 nodes as at 400."""
+    """The search is one host read; the eviction none; the retry, the
+    eviction and one scheduling step as one device program, one. At most two
+    per preemptor, at 40 nodes as at 400; every retry takes the device
+    path."""
     before = metrics.VICTIM_SEARCH.value(path="device")
+    retries = {p: metrics.PREEMPTION_RETRY.value(path=p) for p in ("device", "batch")}
+    tried = sum(metrics.PREEMPTION_ATTEMPTS.value(outcome=o)
+                for o in ("succeeded", "retry_failed"))
     res = simulate_case(1, n_nodes, "resources")
     assert res.preempted
     root = [r for r in tracing.recent_timings() if r["name"] == "simulate"][-1]
     syncs = _syncs_per_preemptor(root)
     assert len(syncs) == metrics.VICTIM_SEARCH.value(path="device") - before
-    assert syncs and max(syncs) <= 4, syncs
+    assert syncs and max(syncs) <= 2, syncs
+    n_retries = metrics.PREEMPTION_RETRY.value(path="device") - retries["device"]
+    assert n_retries == _count(root, "retry") > 0
+    assert n_retries == sum(metrics.PREEMPTION_ATTEMPTS.value(outcome=o)
+                            for o in ("succeeded", "retry_failed")) - tried
+    assert metrics.PREEMPTION_RETRY.value(path="batch") == retries["batch"]
+
+
+def _not_first(ns, carry, pod):
+    return ns.valid & (jnp.arange(ns.valid.shape[0]) > 0)
+
+
+def _first_else_most_packed(ns, carry, pod):
+    return jnp.where(jnp.arange(ns.valid.shape[0]) == 0, 1e6, -carry.free[:, 0])
+
+
+def _two_empty_nodes():
+    """The `resources` case with nodes n000 and n001 empty: with both plugins
+    the retry goes to the most packed node that fits, the evicted one;
+    without the filter to n000, without the score to the empty n001."""
+    objs, pre = case(2, 12, "resources")
+    return [o for o in objs if o["spec"].get("nodeName") not in ("n000", "n001")], pre
+
+
+def _retry_cases():
+    cases = [(kind, lambda k=kind: case(2, 12, k), ()) for kind in KINDS]
+    cases += [(mode, lambda m=mode: coupled_case(1, 12, m), ()) for mode in COUPLED]
+    cases.append(("plugins", _two_empty_nodes, (
+        DevicePlugin(name="not-first", filter_fn=_not_first),
+        DevicePlugin(name="first-else-most-packed", score_fn=_first_else_most_packed,
+                     weight=1000.0))))
+    return cases
+
+
+def _bits(tree):
+    return [np.asarray(a).tobytes() for a in jax.tree.leaves(tree)]
+
+
+@pytest.mark.parametrize("name,make,plugins", _retry_cases(),
+                         ids=[c[0] for c in _retry_cases()])
+def test_retry_program_is_eviction_then_step_zero(name, make, plugins):
+    """retry_one on a preemptor's eviction equals apply_evictions followed by
+    the 32-step grouped scan with one valid step, bit for bit: every carry
+    leaf and every output of step 0. Three preemptors a case, each on the
+    state the previous retry left."""
+    objs, pre = make()
+    sim = Simulator(ClusterResource.from_objects(objs + pre), plugins=list(plugins))
+    sim._build_device_state(sim._pending_cluster)
+    done = 0
+    for pod in [p for p in sim._pending_cluster
+                if p.priority > 0 and p.preemption_policy != "Never"]:
+        res = sim._search_victims(pod)
+        if res is None:
+            continue
+        ni = sim._name_index_map()[res.node]
+        sim._evict(res.victims, ni, by=pod.key)
+        _, rows = sim._evict_rows
+        weights, fo = sim._profiles[pod.scheduler_name]
+        fo = jnp.ones(kernels.NUM_FILTERS, bool) if fo is None else jnp.asarray(fo)
+        row = sim._preemptor_row(pod)
+        offs = sim._victims.offs
+        got_carry, got = preempt.retry_one(
+            sim._ns, sim._carry, np.int32(ni), rows, row, weights, fo, offs=offs,
+            extra_filters=sim._extra_filters, extra_scores=sim._extra_scores)
+        want_carry = preempt.apply_evictions(sim._carry, np.int32(ni), rows, offs=offs)
+        want_carry, want = grouped._group_call(
+            sim._ns, want_carry, row, 32, jnp.int32(1), weights, fo,
+            sim._extra_filters, sim._extra_scores)
+        assert _bits(got_carry) == _bits(want_carry), name
+        assert _bits(got) == _bits(jax.tree.map(lambda a: a[0], want)), name
+        sim._carry = got_carry
+        done += 1
+        if done == 3:
+            break
+    assert done, name
 
 
 def test_extender_sees_every_candidate(stub_factory):
@@ -288,9 +370,19 @@ def test_extender_sees_every_candidate(stub_factory):
     the oracle selects there."""
     objs, pre = case(1, 12, "resources")
     stub = stub_factory({"preempt_allow": None})
-    simulate(ClusterResource.from_objects(objs), [AppResource(name="p", objects=pre[:1])],
-             extenders=[ExtenderConfig(url_prefix=stub.url, preempt_verb="preempt")])
+    retries = {p: metrics.PREEMPTION_RETRY.value(path=p) for p in ("device", "batch")}
+    got = digest(simulate(
+        ClusterResource.from_objects(copy.deepcopy(objs)),
+        [AppResource(name="p", objects=copy.deepcopy(pre[:1]))],
+        extenders=[ExtenderConfig(url_prefix=stub.url, preempt_verb="preempt")]))
     sent = [b for path, b in stub.calls if path.endswith("/preempt")][0]["NodeNameToVictims"]
+    # the extender is interested in the preemptor: its retry takes the batch
+    # path, and the answers are those of the device path without it
+    assert metrics.PREEMPTION_RETRY.value(path="batch") == retries["batch"] + 1
+    assert metrics.PREEMPTION_RETRY.value(path="device") == retries["device"]
+    assert got["preempted"]
+    assert got == digest(simulate(ClusterResource.from_objects(objs),
+                                  [AppResource(name="p", objects=pre[:1])]))
 
     sim = _sim(1, 12, "resources")
     by_node = _bound_by_node(sim)
