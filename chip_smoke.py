@@ -1,8 +1,9 @@
 """Chip smoke: the product path, once, on one TPU chip.
 
 Runs `simon apply` (``open_simulator_tpu.cli.main.main``) with the default
-auto-plan on the north-star capacity plan (BASELINE.json config 5, as in
-``bench.config_plan``): 100k pods onto a 10k-node, 3-zone cluster that
+auto-plan on the north-star capacity plan (BASELINE.json config 5, the
+shapes listed under ``assumed`` in benchmark/configs/plan_100k_10k.json):
+100k pods onto a 10k-node, 3-zone cluster that
 overflows, so the add-node search brackets and bisects. The result is
 checked on the host with numpy, and the placement digests of the stock
 example and of the 10k-pod x 1k-node spread+affinity config (BASELINE.json
@@ -136,7 +137,7 @@ def write_config(root: str, name: str, nodes: list, deploys: list,
 
 
 def plan_config(root: str, seed: int, n_pods: int, n_nodes: int) -> str:
-    """BASELINE.json config 5 (bench.config_plan): 3-cpu/6Gi nodes over
+    """BASELINE.json config 5: 3-cpu/6Gi nodes over
     three zones, `web` (zone spread, maxSkew 50) and `batch`; ~37.5k cpu
     of demand against ~30k of capacity, so the search must add 32-cpu
     `new-node`s. The seed orders the node manifests."""
@@ -153,7 +154,7 @@ def plan_config(root: str, seed: int, n_pods: int, n_nodes: int) -> str:
 
 def spread_affinity_config(root: str, seed: int, n_pods: int = 10_000,
                            n_nodes: int = 1_000) -> str:
-    """BASELINE.json config 3 (bench.config_spread_affinity): `front` under
+    """BASELINE.json config 3: `front` under
     a DoNotSchedule zone spread, `back` preferring `front`'s zone."""
     order = np.random.default_rng(seed).permutation(n_nodes)
     nodes = [_node(f"n-{i}", "32", "64Gi", f"az-{i % 3}") for i in order]

@@ -71,7 +71,6 @@ N_CANON = 64
 LANE_PARALLEL = frozenset({
     "ops.fast:schedule_scenarios",
     "ops.fast:schedule_wave",
-    "ops.fast:commit_choices",
 })
 
 #: Entries that index nodes by *global id* (dynamic_slice over the node

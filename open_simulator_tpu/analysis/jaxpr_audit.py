@@ -72,7 +72,6 @@ AUDIT_TARGETS: Dict[str, Tuple[str, ...]] = {
         "schedule_universes",
         "schedule_wave",
         "schedule_universes_wave",
-        "commit_choices",
     ),
     "open_simulator_tpu.ops.grouped": ("_group_jit",),
     "open_simulator_tpu.ops.kernels": (
@@ -99,7 +98,6 @@ REQUIRED_COVERAGE = frozenset(
         "ops.fast:schedule_universes",
         "ops.fast:schedule_wave",
         "ops.fast:schedule_universes_wave",
-        "ops.fast:commit_choices",
         "ops.grouped:_group_jit",
         "ops.kernels:schedule_batch",
         "ops.kernels:probe_step",
@@ -458,17 +456,12 @@ def _capture_calls() -> List[_Captured]:
         )
         # the conflict-parallel wave engine (ops/wave.py): one Jacobi
         # round at the chunked-driver shapes (cold -1 choices, partial
-        # count so the live gate is traced), the replay-only commit
-        # phase, and the universes-axis round `simon prove --engine
-        # wave` drives — none of these donate their carry
+        # count so the live gate is traced) and the universes-axis round
+        # `simon prove --engine wave` drives — neither donates its carry
         choices_w = jnp.full((s_pad, 4), -1, jnp.int32)
         fast.schedule_wave(
             ns, state_mod.stack_carry(carry, s_pad), rows_c,
             weights_s, valid_s, choices_w, jnp.int32(3),
-        )
-        fast.commit_choices(
-            ns, state_mod.stack_carry(carry, s_pad), rows_c,
-            valid_s, choices_w, jnp.int32(3),
         )
         fast.schedule_universes_wave(
             jax.tree.map(stack_leaf, ns),

@@ -155,13 +155,11 @@ def _run_runs(args) -> int:
             print(f"  [{e['seq']:>4}] {e['event']:<18} {_json.dumps(extra, sort_keys=True)}")
         return 0
 
-    # resume: apply and sweep runs are resumable from the CLI (bench has its
-    # own entry point: `python bench.py --resume RUN_DIR`)
+    # resume: apply and sweep runs are the resumable kinds
     if summary["kind"] not in ("apply", "sweep"):
         print(
             f"error: run {args.run_dir} is kind={summary['kind'] or '?'}; "
-            "`simon runs resume` handles apply and sweep runs — resume "
-            "bench runs with `python bench.py --resume RUN_DIR`",
+            "`simon runs resume` handles apply and sweep runs only",
             file=sys.stderr,
         )
         return 1

@@ -68,7 +68,6 @@ from .kernels import (
     _domain_counts,
     _minmax_normalize,
     combine_scores,
-    commit_choice,
     gpu_allocate_rowwise,
     gpu_mask,
     gpu_share_raw,
@@ -1648,8 +1647,9 @@ def scenario_programs() -> dict:
 def reset_scenario_programs() -> None:
     # reachable from a watchdog-guarded driver callable, but guarded_call's
     # supervising thread parks in done.wait() until the worker finishes —
-    # the callable has the drivers' shared state to itself (benches and
-    # warmup call this between runs, never concurrently with a sweep)
+    # the callable has the drivers' shared state to itself (warmup and the
+    # recompile guard call this between runs, never concurrently with a
+    # sweep)
     _SCENARIO_PROGRAMS.clear()  # osim: audit-ok[race]
 
 
@@ -1926,47 +1926,35 @@ def schedule_universes_wave(
     return jax.vmap(one)(ns_s, carry_s, pods_s, weights_s, choices_s)
 
 
-@sanitizable("ops.fast:commit_choices")
-@jax.jit
-def commit_choices(
-    ns: NodeStatic,
-    carry_s: Carry,
-    pods: PodRow,
-    valid_s: jnp.ndarray,
-    choices_s: jnp.ndarray,
-    count: jnp.ndarray,
-):
-    """The wave engine's COMMIT PHASE in isolation: replay decided
-    choices (i32[S,W], -1 = no commit) through `kernels.commit_choice` —
-    the row-wise O(row) commit — with no probe and no prefix-carry
-    stacking. This is the only part of the wave engine that is
-    inherently sequential (each commit reads the previous commit's
-    carry), so its wall time is the engine's sequential depth; the
-    `wave_commit_10k` bench gates it at ≥10× faster than the serial
-    decide+commit scan. Byte-identical to replaying the same choices
-    through the serial scan (see commit_choice's bit-identity note).
+def _wave_fixpoint(round_fn, shape, limit, live, site, reason):
+    """Drive a wave round function to its fixpoint.
 
-    Returns (carry_s, gpu_take i32[S,W,G], vg_take f32[S,W,V],
-    dev_take f32[S,W,DV]). `carry_s` is not donated (callers may retry
-    a wave after a fault injection)."""
-    w = int(jax.tree_util.tree_leaves(pods)[0].shape[0])
-    idx = jnp.arange(w, dtype=jnp.int32)
+    `round_fn(choices, r)` runs round `r` on the previous round's choices
+    (all -1 for round 1) and returns device outputs whose second element
+    is the round's own choices. The loop stops at the first round that
+    returns the choices it was given: that round's outputs are the serial
+    scan's (ops/wave.py; at most W+1 rounds). From round 2 on, every
+    choice in the `live` slice that changed counts as a wave conflict.
+    After `limit` rounds without a fixpoint (0 = no limit) the fallback
+    is counted under `reason` and no outputs are returned; the caller
+    replays through its serial kernel.
 
-    def one(valid, carry, choices):
-        ns_l = ns._replace(valid=valid)
-        gated = jnp.where(idx < count, choices, jnp.int32(-1))
-
-        def step(c, xs):
-            pod, choice = xs
-            c2, gpu_take, vg_take, dev_take = commit_choice(
-                ns_l, c, pod, choice
-            )
-            return c2, (gpu_take.astype(jnp.int32), vg_take, dev_take)
-
-        final, takes = jax.lax.scan(step, carry, (pods, gated))
-        return (final,) + takes
-
-    return jax.vmap(one)(valid_s, carry_s, choices_s)
+    Returns (rounds, outputs or None, host choices of the last round)."""
+    choices = jnp.full(shape, -1, jnp.int32)
+    prev = np.full(shape, -1, np.int32)
+    rounds = 0
+    while True:
+        rounds += 1
+        out = round_fn(choices, rounds)
+        ch = np.asarray(fetch(out[1], site))
+        if np.array_equal(ch, prev):
+            return rounds, out, ch
+        if rounds > 1:
+            _metrics.WAVE_CONFLICTS.inc(int((ch[live] != prev[live]).sum()))
+        if limit and rounds >= limit:
+            _metrics.WAVE_FALLBACKS.inc(reason=reason)
+            return rounds, None, ch
+        choices, prev = out[1], ch
 
 
 def schedule_universes_wave_host(
@@ -1980,34 +1968,25 @@ def schedule_universes_wave_host(
     return tuple as schedule_universes (which donates its carry; this
     driver instead keeps the input carry alive across rounds and returns
     the converged round's exit carry). Guaranteed to converge within W+1
-    rounds (ops/wave.py); the impossible-overrun guard falls back to the
-    serial oracle rather than looping."""
+    rounds (ops/wave.py); the impossible-overrun guard (round W+2) falls
+    back to the serial oracle rather than looping."""
     s_pad = int(jax.tree_util.tree_leaves(carry_s)[0].shape[0])
     p_pad = int(jax.tree_util.tree_leaves(pods_s)[0].shape[1])
-    choices = jnp.full((s_pad, p_pad), -1, jnp.int32)
-    prev = np.full((s_pad, p_pad), -1, np.int32)
-    rounds = 0
-    while True:
-        rounds += 1
-        _progress(f"universes-wave S={s_pad} P={p_pad} round {rounds}")
-        carry_w, choices_new, reasons, gpu_take, vg_take, dev_take = (
-            schedule_universes_wave(
-                ns_s, carry_s, pods_s, weights_s, choices, filter_on
-            )
+
+    def round_fn(choices, r):
+        _progress(f"universes-wave S={s_pad} P={p_pad} round {r}")
+        return schedule_universes_wave(
+            ns_s, carry_s, pods_s, weights_s, choices, filter_on
         )
-        ch = np.asarray(fetch(choices_new, "universes-wave-round"))
-        if np.array_equal(ch, prev):
-            break
-        if rounds > 1:
-            _metrics.WAVE_CONFLICTS.inc(int((ch != prev).sum()))
-        if rounds > p_pad + 1:
-            _metrics.WAVE_FALLBACKS.inc(reason="universes_max_rounds")
-            return schedule_universes(
-                ns_s, carry_s, pods_s, weights_s, filter_on
-            )
-        choices, prev = choices_new, ch
+
+    rounds, out, _ = _wave_fixpoint(
+        round_fn, (s_pad, p_pad), p_pad + 2, ..., "universes-wave-round",
+        "universes_max_rounds",
+    )
+    if out is None:
+        return schedule_universes(ns_s, carry_s, pods_s, weights_s, filter_on)
     _metrics.COMMIT_ROUNDS.observe(rounds)
-    return carry_w, choices_new, reasons, gpu_take, vg_take, dev_take
+    return out
 
 
 def schedule_scenarios_host(
@@ -2032,34 +2011,65 @@ def schedule_scenarios_host(
     from ops.state.stack_carry is freshly materialized per sweep, so the
     simulator's own serial carry is never at risk.
 
-    With OSIM_COMMIT_CHUNK > 0 (and more pods than one chunk) the dispatch
-    is the chunked commit driver instead: ceil(P/C) count-gated
-    schedule_scenarios_chunked calls whose chained result is byte-identical
-    to the single scan, with a checkpoint hook between chunks
-    (durable/checkpoint.py) and device-fault recovery — see
-    docs/durability.md.
-
     With the wave engine enabled (OSIM_WAVE_COMMIT / auto above
-    ops.wave.WAVE_AUTO_MIN_PODS pods) the dispatch is the
-    conflict-parallel wave driver instead — byte-identical to the serial
-    scan by fixpoint construction (docs/performance.md), checkpointing
-    one wave per `plan_chunk` record with the same digest chain a serial
-    chunked run of chunk = wave size would journal."""
+    ops.wave.WAVE_AUTO_MIN_PODS pods) each chunk of the durable chunk loop
+    (_commit_in_chunks) is one wave driven to its fixpoint — byte-identical
+    to the serial scan by fixpoint construction (docs/performance.md).
+    Otherwise, with OSIM_COMMIT_CHUNK > 0 and more pods than one chunk,
+    each chunk is one count-gated schedule_scenarios_chunked call, whose
+    chained result is byte-identical to the single scan. Either way the
+    loop checkpoints between chunks (durable/checkpoint.py) and recovers
+    from device faults — see docs/durability.md."""
     rows = pod_rows_from_batch(batch)
     s_pad = int(valid_s.shape[0])
-    key = (int(ns.valid.shape[0]), int(batch.p))
+    p_real = int(batch.p)
+    key = (int(ns.valid.shape[0]), p_real)
     _SCENARIO_PROGRAMS.setdefault(key, set()).add(s_pad)
     _metrics.SCENARIOS_PER_CALL.observe(s_real)
-    if _wave.wave_enabled(int(batch.p)):
-        return _schedule_scenarios_wave_host(
-            ns, carry_s, rows, weights_s, valid_s, s_real, s_pad,
-            int(batch.p), _wave.wave_size(), filter_on,
+
+    def serial_chunk(carry_s, rows_c, count, site="scenario-chunk"):
+        carry_s, *outs = schedule_scenarios_chunked(
+            ns, carry_s, rows_c, weights_s, valid_s, jnp.int32(count),
+            filter_on,
+        )
+        got = fetch(tuple(outs), site)
+        return carry_s, tuple(np.asarray(a)[:, :count] for a in got)
+
+    if _wave.wave_enabled(p_real):
+        wave = _wave.wave_size()
+        max_rounds = _wave.wave_max_rounds()
+
+        def wave_chunk(carry_s, rows_c, count):
+            def round_fn(choices, r):
+                _progress(f"  wave round {r} (W={wave}, live={count})")
+                return schedule_wave(
+                    ns, carry_s, rows_c, weights_s, valid_s, choices,
+                    jnp.int32(count), filter_on,
+                )
+
+            rounds, out, ch = _wave_fixpoint(
+                round_fn, (s_pad, wave), max_rounds,
+                np.s_[:s_real, :count], "wave-round", "max_rounds",
+            )
+            _metrics.COMMIT_ROUNDS.observe(rounds)
+            if out is None:
+                _progress(
+                    f"  no fixpoint in {rounds} rounds; replaying the wave "
+                    "through the serial chunk kernel"
+                )
+                return serial_chunk(carry_s, rows_c, count, "wave-fallback")
+            got = fetch(out[2:], "wave-commit")
+            return out[0], (np.ascontiguousarray(ch[:, :count]),) + tuple(
+                np.asarray(a)[:, :count] for a in got
+            )
+
+        return _commit_in_chunks(
+            ns, carry_s, rows, s_real, s_pad, p_real, wave, wave_chunk
         )
     chunk = commit_chunk_size()
-    if chunk and int(batch.p) > chunk:
-        return _schedule_scenarios_chunked_host(
-            ns, carry_s, rows, weights_s, valid_s, s_real, s_pad,
-            int(batch.p), chunk, filter_on,
+    if chunk and p_real > chunk:
+        return _commit_in_chunks(
+            ns, carry_s, rows, s_real, s_pad, p_real, chunk, serial_chunk
         )
     _progress(
         f"scenarios S={s_real}/{s_pad} P={batch.p} N={ns.valid.shape[0]}"
@@ -2071,37 +2081,44 @@ def schedule_scenarios_host(
     return (carry_s,) + tuple(np.asarray(a)[:s_real] for a in got)
 
 
-def _schedule_scenarios_chunked_host(
+def _commit_in_chunks(
     ns: NodeStatic,
     carry_s: Carry,
     rows: PodRow,
-    weights_s: jnp.ndarray,
-    valid_s: jnp.ndarray,
     s_real: int,
     s_pad: int,
     p_real: int,
-    chunk: int,
-    filter_on=None,
+    size: int,
+    commit,
 ):
-    """The outer host loop of the chunked commit driver.
+    """The durable outer loop of the chunked and wave commit drivers.
 
-    Per chunk: optional device-fault injection, one
-    schedule_scenarios_chunked dispatch, host transfer of the chunk's
-    outputs, then the checkpoint hook (journal `plan_chunk` + periodic
-    atomic carry snapshot, durable/checkpoint.py). On resume the active
-    checkpointer hands back a verified snapshot: the loop re-pins its carry
-    onto the current mesh (carry_from_host), counts the covered chunks as
-    skipped, and re-executes only the journal tail — cross-checking every
-    re-executed chunk's digest against the journaled one. A DeviceLostError
-    from the fault plane rolls back to the last good in-memory snapshot and
-    replays (degraded, not failed) until the strike budget runs out."""
+    `commit(carry_s, rows_c, count)` commits one chunk of `size` pod rows,
+    the first `count` of them live, and returns the exit carry with the
+    host (nodes, reasons, gpu, vg, dev) outputs trimmed to `count`: one
+    schedule_scenarios_chunked call, or one wave driven to its fixpoint.
+    Both leave the same carry, so the plan key and the `plan_chunk`
+    digest chain are the same for either, and a plan checkpointed by one
+    resumes under the other.
+
+    Per chunk: optional device-fault injection, the commit, then the
+    checkpoint hook (journal `plan_chunk` + periodic atomic carry
+    snapshot, durable/checkpoint.py). On resume the active checkpointer
+    hands back a verified snapshot: the loop re-pins its carry onto the
+    current mesh (carry_from_host), counts the covered chunks as skipped,
+    and re-executes only the journal tail — cross-checking every
+    re-executed chunk's digest against the journaled one. A
+    DeviceLostError from the fault plane rolls back to the last good
+    in-memory snapshot and replays (degraded, not failed) until the
+    strike budget runs out; a wave's in-flight rounds mutate nothing, so
+    they are simply discarded."""
     from ..durable import checkpoint as _checkpoint
     from ..resilience import faults as _faults
     from ..utils import flightrec as _flightrec
 
     N = int(ns.valid.shape[0])
-    n_chunks = -(-p_real // chunk)
-    p_pad = n_chunks * chunk
+    n_chunks = -(-p_real // size)
+    p_pad = n_chunks * size
     if p_pad != p_real:
         rows = jax.tree_util.tree_map(
             lambda a: jnp.concatenate(
@@ -2109,7 +2126,7 @@ def _schedule_scenarios_chunked_host(
             ),
             rows,
         )
-    _SCENARIO_PROGRAMS.setdefault((N, chunk), set()).add(s_pad)
+    _SCENARIO_PROGRAMS.setdefault((N, size), set()).add(s_pad)
 
     cp = _checkpoint.active_checkpointer()
     plan = None
@@ -2117,7 +2134,7 @@ def _schedule_scenarios_chunked_host(
     outs: list = []  # host (nodes, reasons, gpu, vg, dev) tuples, in order
     if cp is not None:
         plan = cp.begin_plan(
-            n_nodes=N, p_real=p_real, s_pad=s_pad, chunk=chunk,
+            n_nodes=N, p_real=p_real, s_pad=s_pad, chunk=size,
             n_chunks=n_chunks,
         )
         restore = plan.restore
@@ -2168,196 +2185,15 @@ def _schedule_scenarios_chunked_host(
                 del outs[g_outs:]
                 i = g_chunk
                 continue
-        lo = i * chunk
-        count = min(chunk, p_real - lo)
+        lo = i * size
+        count = min(size, p_real - lo)
         _progress(
             f"scenarios S={s_real}/{s_pad} N={N} "
-            f"chunk {i + 1}/{n_chunks} (C={chunk}, live={count})"
+            f"chunk {i + 1}/{n_chunks} (C={size}, live={count})"
         )
-        rows_c = jax.tree_util.tree_map(lambda a: a[lo:lo + chunk], rows)
-        carry_s, nodes, reasons, gpu_take, vg_take, dev_take = (
-            schedule_scenarios_chunked(
-                ns, carry_s, rows_c, weights_s, valid_s,
-                jnp.int32(count), filter_on,
-            )
-        )
-        got = fetch(
-            (nodes, reasons, gpu_take, vg_take, dev_take), "scenario-chunk"
-        )
-        outs.append(tuple(np.asarray(a)[:, :count] for a in got))
-        _metrics.PLAN_CHUNKS.inc()
-        if cp is not None:
-            digest = scenario_carry_digest(carry_s)
-            hostc = cp.on_chunk(plan, i, lo + count, digest, carry_s, outs)
-            if hostc is not None:
-                last_good = (i + 1, hostc, len(outs), digest)
-        i += 1
-
-    if cp is not None:
-        cp.finish_plan(plan, scenario_carry_digest(carry_s))
-    cat = tuple(
-        np.concatenate([o[k] for o in outs], axis=1) for k in range(5)
-    )
-    return (carry_s,) + tuple(a[:s_real] for a in cat)
-
-
-def _schedule_scenarios_wave_host(
-    ns: NodeStatic,
-    carry_s: Carry,
-    rows: PodRow,
-    weights_s: jnp.ndarray,
-    valid_s: jnp.ndarray,
-    s_real: int,
-    s_pad: int,
-    p_real: int,
-    wave: int,
-    filter_on=None,
-):
-    """The outer host loop of the conflict-parallel wave commit driver.
-
-    Structure is _schedule_scenarios_chunked_host's with one wave per
-    chunk slot: per wave, iterate schedule_wave rounds until the probe
-    reproduces its own input choices (the fixpoint — byte-identical to
-    the serial scan, ops/wave.py), then adopt that round's exit carry
-    and outputs. A wave that exhausts OSIM_WAVE_ROUNDS is re-run through
-    the serial chunked kernel (the oracle path; counted in
-    osim_wave_fallbacks_total) so the driver is never slower than
-    serial + the round budget, and never wrong.
-
-    Durability and fault handling are inherited wholesale: one
-    `plan_chunk` journal record per committed wave with the same
-    scenario-carry digest chain a serial chunked run (C = wave) would
-    write — so a wave plan resumes from a serial run's snapshot and vice
-    versa — and device-loss rolls back to the last good committed wave
-    (in-flight rounds are discarded; rounds mutate nothing until the
-    fixpoint is adopted)."""
-    from ..durable import checkpoint as _checkpoint
-    from ..resilience import faults as _faults
-    from ..utils import flightrec as _flightrec
-
-    N = int(ns.valid.shape[0])
-    n_waves = -(-p_real // wave)
-    p_pad = n_waves * wave
-    if p_pad != p_real:
-        rows = jax.tree_util.tree_map(
-            lambda a: jnp.concatenate(
-                [a, jnp.broadcast_to(a[:1], (p_pad - p_real,) + a.shape[1:])]
-            ),
-            rows,
-        )
-    _SCENARIO_PROGRAMS.setdefault((N, wave), set()).add(s_pad)
-    max_rounds = _wave.wave_max_rounds()
-
-    cp = _checkpoint.active_checkpointer()
-    plan = None
-    start_wave = 0
-    outs: list = []  # host (nodes, reasons, gpu, vg, dev) tuples, in order
-    if cp is not None:
-        plan = cp.begin_plan(
-            n_nodes=N, p_real=p_real, s_pad=s_pad, chunk=wave,
-            n_chunks=n_waves,
-        )
-        restore = plan.restore
-        if restore is not None:
-            start_wave = restore.chunks_done
-            carry_s = carry_from_host(carry_s, restore.carry)
-            outs.append(restore.outputs)
-            _metrics.RESUME_CHUNKS_SKIPPED.inc(start_wave)
-            _flightrec.note(
-                "plan-restore", plan=plan.key, chunk=start_wave - 1,
-                digest=f"{restore.digest:08x}",
-            )
-            _flightrec.dump("chunk-restore", run_dir=cp.run_dir)
-
-    track = cp is not None or _faults.has_rules("device")
-    last_good = None  # (wave_idx, host carry leaves, len(outs), digest)
-    if track:
-        host0 = carry_to_host(carry_s)
-        last_good = (
-            start_wave, host0, len(outs), scenario_carry_digest_host(host0),
-        )
-    strikes = 0
-
-    i = start_wave
-    while i < n_waves:
-        rule = _faults.maybe_inject("device", f"commit-chunk:{i}")
-        if rule is not None:
-            try:
-                _faults.apply_device_fault(rule)
-            except _faults.DeviceLostError:
-                strikes += 1
-                if last_good is None or strikes >= 3:
-                    _metrics.DEVICE_LOST.inc(handled="no")
-                    raise
-                _metrics.DEVICE_LOST.inc(handled="yes")
-                g_wave, g_carry, g_outs, g_digest = last_good
-                _flightrec.note(
-                    "device-lost", chunk=i, restored_to=g_wave,
-                    digest=f"{g_digest:08x}",
-                )
-                _flightrec.dump(
-                    "device-lost",
-                    run_dir=cp.run_dir if cp is not None else None,
-                )
-                carry_s = carry_from_host(carry_s, g_carry)
-                del outs[g_outs:]
-                i = g_wave
-                continue
-        lo = i * wave
-        count = min(wave, p_real - lo)
-        rows_w = jax.tree_util.tree_map(lambda a: a[lo:lo + wave], rows)
-        choices = jnp.full((s_pad, wave), -1, jnp.int32)
-        prev = np.full((s_pad, wave), -1, np.int32)
-        rounds = 0
-        converged = False
-        while True:
-            rounds += 1
-            _progress(
-                f"scenarios S={s_real}/{s_pad} N={N} "
-                f"wave {i + 1}/{n_waves} round {rounds} "
-                f"(W={wave}, live={count})"
-            )
-            carry_w, choices_new, reasons, gpu_take, vg_take, dev_take = (
-                schedule_wave(
-                    ns, carry_s, rows_w, weights_s, valid_s, choices,
-                    jnp.int32(count), filter_on,
-                )
-            )
-            ch = np.asarray(fetch(choices_new, "wave-round"))
-            if np.array_equal(ch, prev):
-                converged = True
-                break
-            if rounds > 1:
-                _metrics.WAVE_CONFLICTS.inc(
-                    int((ch[:s_real, :count] != prev[:s_real, :count]).sum())
-                )
-            if max_rounds and rounds >= max_rounds:
-                break
-            choices, prev = choices_new, ch
-        _metrics.COMMIT_ROUNDS.observe(rounds)
-        if converged:
-            carry_s = carry_w
-            got = fetch((reasons, gpu_take, vg_take, dev_take), "wave-commit")
-            outs.append(
-                (np.ascontiguousarray(ch[:, :count]),)
-                + tuple(np.asarray(a)[:, :count] for a in got)
-            )
-        else:
-            _metrics.WAVE_FALLBACKS.inc(reason="max_rounds")
-            _progress(
-                f"wave {i + 1}/{n_waves}: no fixpoint in {rounds} rounds; "
-                "replaying through the serial chunk kernel"
-            )
-            carry_s, nodes, reasons, gpu_take, vg_take, dev_take = (
-                schedule_scenarios_chunked(
-                    ns, carry_s, rows_w, weights_s, valid_s,
-                    jnp.int32(count), filter_on,
-                )
-            )
-            got = fetch(
-                (nodes, reasons, gpu_take, vg_take, dev_take), "wave-fallback"
-            )
-            outs.append(tuple(np.asarray(a)[:, :count] for a in got))
+        rows_c = jax.tree_util.tree_map(lambda a: a[lo:lo + size], rows)
+        carry_s, out = commit(carry_s, rows_c, count)
+        outs.append(out)
         _metrics.PLAN_CHUNKS.inc()
         if cp is not None:
             digest = scenario_carry_digest(carry_s)

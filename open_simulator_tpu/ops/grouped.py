@@ -70,8 +70,8 @@ from ..utils.tracing import fetch
 
 # Default cap on per-group device-program length (scan steps per dispatch) —
 # one 100k-step scan trips the TPU worker's watchdog, so dispatches are
-# bounded. Shared by schedule_batch_grouped, schedule_batch_fast and
-# bench.py's OSIM_HEADLINE_CHUNK default/stamp so the sites cannot drift.
+# bounded. Shared by schedule_batch_grouped and schedule_batch_fast so the
+# sites cannot drift.
 DEFAULT_GROUP_CHUNK = 16384
 
 

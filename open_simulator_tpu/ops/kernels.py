@@ -1125,9 +1125,9 @@ def commit_choice(ns: NodeStatic, carry: Carry, pod: PodRow, choice):
     nonnegative, so no -0.0 products), which is bitwise identity, and the
     chosen row sees `1.0 * delta` which is bitwise `delta`; the gpu and
     storage takes follow the gpu_allocate_rowwise row-extraction
-    argument. This is the wave engine's replay step (ops/wave.py) and the
-    commit phase of `ops.fast:commit_choices`; `simon prove` holds it to
-    the banked digest over the full small-scope corpus."""
+    argument. This is the wave engine's replay step (ops/wave.py);
+    `simon prove` holds it to the banked digest over the full small-scope
+    corpus."""
     n = ns.valid.shape[0]
     ok = (choice >= 0) & pod.valid
     row = jnp.where(ok, choice, 0)        # safe gather index

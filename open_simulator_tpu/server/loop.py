@@ -125,13 +125,6 @@ class SchedulerLoop:
         # re-posting herd, not an idle-server request.
         self._last_pack_lanes = 0
         self._last_pack_end: Optional[float] = None
-        # Bench-only switch (bench.py serving_saturation): when True the
-        # window reverts to the PRE-loop semantics — a latency floor every
-        # pack waits out, pack_ready ignored — so the replaced coalesce-
-        # window-then-cold-dispatch architecture can be measured as the
-        # baseline of the continuous-batching speedup claim. Never set in
-        # production paths.
-        self.legacy_floor = False
 
     # -- loop driver --------------------------------------------------------
 
@@ -165,7 +158,7 @@ class SchedulerLoop:
                         and q._clock() - self._last_pack_end
                         < self.pack_window_s
                     )
-                    if not self.legacy_floor and pack_ready(
+                    if pack_ready(
                         len(q._queue), depth=q.depth,
                         pack_lanes=self.pack_lanes, saturated=saturated,
                     ):

@@ -97,7 +97,7 @@ _resident = None  # Optional[engine.resident.ResidentCluster]
 # a concurrent second user of the same key falls back to the cold path rather
 # than blocking the scheduler loop. Capacity-capped LRU; any session error
 # drops the entry (cold path is always correct). OSIM_SERVER_LOOP=0 disables
-# the cache entirely (the bench's baseline mode).
+# the cache entirely (every pack takes the cold path).
 _SESSION_CAP = 8
 _sessions_lock = threading.Lock()
 _sessions: "OrderedDict[tuple, dict]" = OrderedDict()
@@ -158,7 +158,8 @@ def _scenario_compat_key(body: dict) -> str:
 
 def _loop_sessions_enabled() -> bool:
     """OSIM_SERVER_LOOP gates the warm-session cache (default on). Resolved
-    at call time, not import time, so the bench can flip it per mode."""
+    at call time, not import time, so a change takes effect on the next
+    pack."""
     raw = os.environ.get("OSIM_SERVER_LOOP", "").strip().lower()
     return raw not in ("0", "false", "off", "no")
 

@@ -42,8 +42,7 @@ def keepalive_enabled() -> bool:
     """OSIM_EXTENDER_KEEPALIVE: 0 routes extender HTTP through the legacy
     fresh-connection-per-request transport (`urllib.request.urlopen`) instead
     of these pools — the transport escape hatch for proxies or servers that
-    misbehave on persistent connections, and the bench's `legacy_serial`
-    baseline."""
+    misbehave on persistent connections."""
     return os.environ.get("OSIM_EXTENDER_KEEPALIVE", "1") != "0"
 
 

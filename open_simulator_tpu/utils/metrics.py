@@ -18,7 +18,8 @@ Parity table (ours -> kube-scheduler):
 
 Exposure paths:
   * `GET /metrics` on the HTTP server (Prometheus text format 0.0.4);
-  * `snapshot()` embedded in bench.py output JSON;
+  * `snapshot()` as JSON (`simon apply --metrics-file`, flight-recorder
+    dumps, the benchmark's per-layer counters);
   * every `tracing.span()` observes into a histogram via `observe_span()`.
 
 Hand-rolled on purpose: the image pins jax/numpy/pyyaml only, and the subset
@@ -355,7 +356,7 @@ class MetricsRegistry:
         return out
 
     def snapshot(self, include_empty: bool = False) -> Dict[str, dict]:
-        """JSON-friendly dump (embedded in bench.py output)."""
+        """JSON-friendly dump of every metric family."""
         with self._lock:
             families = sorted(self._metrics.items())
         out = {}

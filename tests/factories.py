@@ -14,6 +14,8 @@ from __future__ import annotations
 
 from typing import Dict, List, Optional
 
+import numpy as np
+
 from open_simulator_tpu.core.objects import Node, Pod
 
 
@@ -204,3 +206,120 @@ def make_cronjob(name, namespace="default", cpu="100m", memory="128Mi",
             "jobTemplate": {"spec": inner["spec"]},
         },
     }
+
+
+def build_state(n_nodes: int, n_pods: int):
+    """Encoded (NodeStatic, Carry, PodBatch) for a tiled plan: `n_nodes`
+    nodes over 3 zones and instance types (every 10th tainted), and
+    `n_pods` pods split over 4 templates (zone-spread web and sidecar, a
+    tolerating batch job, a selector-pinned cache). Seeded, so every call
+    with the same sizes encodes the same state."""
+    from open_simulator_tpu.ops.encode import (
+        Encoder,
+        encode_nodes,
+        encode_pods,
+        initial_selector_counts,
+    )
+    from open_simulator_tpu.ops.state import (
+        carry_from_table,
+        node_static_from_table,
+    )
+    from open_simulator_tpu.ops.tile import tile_pod_batch
+
+    rng = np.random.default_rng(0)
+    nodes = []
+    for i in range(n_nodes):
+        taints = (
+            [{"key": "dedicated", "value": "batch", "effect": "NoSchedule"}]
+            if i % 10 == 0
+            else []
+        )
+        nodes.append(
+            Node.from_dict(
+                {
+                    "metadata": {
+                        "name": f"node-{i}",
+                        "labels": {
+                            "kubernetes.io/hostname": f"node-{i}",
+                            "topology.kubernetes.io/zone": f"az-{i % 3}",
+                            "node.kubernetes.io/instance-type": ["m5.4x", "m5.8x", "c5.9x"][i % 3],
+                        },
+                    },
+                    "spec": {"taints": taints},
+                    "status": {
+                        "allocatable": {
+                            "cpu": str(16 + 16 * int(rng.integers(0, 3))),
+                            "memory": f"{32 + 32 * int(rng.integers(0, 3))}Gi",
+                            "pods": "110",
+                        }
+                    },
+                }
+            )
+        )
+
+    # Workload templates: a service with zone spread, a tolerating batch job,
+    # a selector-pinned cache, a plain web tier.
+    templates = []
+    tmpl_specs = [
+        dict(
+            cpu="500m", mem="512Mi", labels={"app": "web"},
+            spread=True, tol=False, sel=None,
+        ),
+        dict(
+            cpu="2", mem="4Gi", labels={"app": "batch"},
+            spread=False, tol=True, sel=None,
+        ),
+        dict(
+            cpu="1", mem="2Gi", labels={"app": "cache"},
+            spread=False, tol=False, sel={"node.kubernetes.io/instance-type": "m5.8x"},
+        ),
+        dict(
+            cpu="250m", mem="256Mi", labels={"app": "sidecar"},
+            spread=True, tol=False, sel=None,
+        ),
+    ]
+    for t, s in enumerate(tmpl_specs):
+        spec = {
+            "containers": [
+                {"name": "c", "resources": {"requests": {"cpu": s["cpu"], "memory": s["mem"]}}}
+            ]
+        }
+        if s["spread"]:
+            spec["topologySpreadConstraints"] = [
+                {
+                    "maxSkew": 50,
+                    "topologyKey": "topology.kubernetes.io/zone",
+                    "whenUnsatisfiable": "ScheduleAnyway",
+                    "labelSelector": {"matchLabels": s["labels"]},
+                }
+            ]
+        if s["tol"]:
+            spec["tolerations"] = [
+                {"key": "dedicated", "operator": "Equal", "value": "batch",
+                 "effect": "NoSchedule"}
+            ]
+        if s["sel"]:
+            spec["nodeSelector"] = s["sel"]
+        templates.append(
+            Pod.from_dict(
+                {
+                    "metadata": {
+                        "name": f"tpl-{t}", "namespace": "bench", "labels": s["labels"],
+                    },
+                    "spec": spec,
+                }
+            )
+        )
+
+    share = n_pods // len(templates)
+    counts = [share] * len(templates)
+    counts[0] += n_pods - share * len(templates)
+
+    enc = Encoder()
+    enc.register_pods(templates)
+    table = encode_nodes(enc, nodes)
+    tmpl_batch = encode_pods(enc, templates)
+    batch = tile_pod_batch(tmpl_batch, counts)
+    ns = node_static_from_table(enc, table)
+    carry = carry_from_table(table, initial_selector_counts(enc, table, []))
+    return ns, carry, batch

@@ -55,7 +55,7 @@ CHUNK = 4  # 24 pods bucket to 32 -> 8 chunks; one shared program per test
 
 @pytest.fixture(scope="module")
 def plan_state():
-    from bench import build_state
+    from tests.factories import build_state
 
     ns, carry, batch = build_state(8, 24)
     s_pad = fast.scenario_bucket(S_REAL)

@@ -120,17 +120,17 @@ def _pod(name, cpu="500m", mem="512Mi", labels=None, spec_extra=None, anno=None)
 
 
 def test_fast_matches_naive_tiled_mix():
-    """The bench workload: spread + tolerations + selectors, 4 templates."""
-    from bench import build_state
+    """The tiled plan workload: spread + tolerations + selectors, 4 templates."""
+    from tests.factories import build_state
 
     ns, carry, batch = build_state(24, 400)
     _assert_identical(ns, carry, batch)
 
 
 def test_fast_triggers_without_force_on_big_groups():
-    """The heuristic itself must pick the fast path for bench-shaped groups
+    """The heuristic itself must pick the fast path for plan-shaped groups
     (nodes cap at 110 pods; groups of 600 >> 2*J)."""
-    from bench import build_state
+    from tests.factories import build_state
 
     ns, carry, batch = build_state(16, 2400)
     _assert_identical(ns, carry, batch, force_fast=False)

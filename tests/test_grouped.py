@@ -61,7 +61,7 @@ def test_grouped_matches_naive_on_synthetic_mix():
     # grouped path needs the numpy batch; rebuild state with the same seed
     from open_simulator_tpu.core.objects import Node, Pod  # noqa
 
-    # Recreate via the bench builder for a template-tiled case below instead.
+    # Recreate via tests.factories.build_state for a template-tiled case below instead.
     del FakeBatch
 
     # For this test, wrap rows back into numpy arrays with batch semantics:
@@ -81,7 +81,7 @@ def _rows_to_batch(rows):
 
 
 def test_grouped_matches_naive_on_tiled_templates():
-    from bench import build_state
+    from tests.factories import build_state
 
     ns, carry, batch = build_state(64, 256)
     w = weights_array()

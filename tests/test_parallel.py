@@ -159,7 +159,7 @@ def test_fast_path_under_mesh_matches_single_device():
     big groups route through build_trajectory + light_scan with sharded
     ns/carry — placements, reasons and the exit carry must equal the
     unsharded run exactly (the `simon apply --devices N` path at scale)."""
-    from bench import build_state
+    from tests.factories import build_state
     from open_simulator_tpu.ops.fast import schedule_batch_fast
 
     ns, carry, batch = build_state(64, 512)

@@ -107,13 +107,26 @@ def test_trace_delegation_for_jaxpr_audit():
 def test_simulation_parity_plain_vs_sanitized(monkeypatch):
     """A real end-to-end sweep places identically with the sanitizer armed
     (observational mode): same scheduled/unscheduled counts."""
-    from bench import _mk_deploy, _mk_node, _simulate_config
+    from open_simulator_tpu.engine.simulator import (
+        AppResource,
+        ClusterResource,
+        simulate,
+    )
+    from tests.factories import make_deployment, make_node
 
-    nodes = [_mk_node(f"n-{i}", "16", "32Gi") for i in range(8)]
-    deploys = [_mk_deploy("web", 24, "500m", "1Gi")]
+    def placed_unsched():
+        nodes = [make_node(f"n-{i}", "16", "32Gi") for i in range(8)]
+        deploy = make_deployment("web", 24, cpu="500m", memory="1Gi")
+        result = simulate(
+            ClusterResource(nodes=nodes),
+            [AppResource(name="web", objects=[deploy])],
+        )
+        placed = sum(len(st.pods) for st in result.node_status)
+        return placed, len(result.unscheduled)
+
     monkeypatch.delenv(SANITIZE_ENV, raising=False)
-    _, plain_placed, plain_unsched = _simulate_config(nodes, deploys)
+    plain_placed, plain_unsched = placed_unsched()
     monkeypatch.setenv(SANITIZE_ENV, "1")
-    _, san_placed, san_unsched = _simulate_config(nodes, deploys)
+    san_placed, san_unsched = placed_unsched()
     assert (san_placed, san_unsched) == (plain_placed, plain_unsched)
     assert plain_placed == 24

@@ -17,6 +17,11 @@ The contract under test, end to end on a small plan (N=8, 24 pods,
     serially, serial plan resumed by the wave driver);
   - device_lost faults roll back to the last committed wave and replay
     in place (in-flight rounds mutate nothing);
+  - the fixpoint loop both drivers share stops at the first repeated
+    round, counts conflicts from round 2 on inside the live slice only,
+    and falls back at its round limit; the universes driver equals
+    schedule_universes bit for bit, and its overrun guard replays
+    through it;
   - auto mode routes to the wave driver only on a parallel backend and a
     plan big enough to amortize the rounds — tier-1 CPU runs stay serial
     unless a test forces the engine on.
@@ -34,7 +39,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from open_simulator_tpu.analysis import sarif
+from open_simulator_tpu.analysis import sarif, semantics
 from open_simulator_tpu.durable import RunJournal, replay
 from open_simulator_tpu.durable.checkpoint import (
     OUTPUT_NAMES,
@@ -56,7 +61,7 @@ WAVE = 4  # shares the (N=8, W=4) program across tests
 
 @pytest.fixture(scope="module")
 def plan_state():
-    from bench import build_state
+    from tests.factories import build_state
 
     ns, carry, batch = build_state(8, 24)
     s_pad = fast.scenario_bucket(S_REAL)
@@ -212,6 +217,135 @@ def test_wave_max_rounds_fallback_stays_identical(plan_state, monkeypatch):
     assert metrics.WAVE_FALLBACKS.value(
         reason="max_rounds"
     ) == fb0 + n_live_waves
+
+
+# ---------------------------------------------------------------------------
+# The shared fixpoint loop (fast._wave_fixpoint), driven by host-only rounds
+# ---------------------------------------------------------------------------
+
+def _scripted_rounds(script):
+    """A host-only round function: round r returns script[r - 1] as its
+    choices (the last entry repeats) and records the choices it was fed."""
+    fed = []
+
+    def round_fn(choices, r):
+        fed.append(np.asarray(choices).copy())
+        ch = np.asarray(script[min(r, len(script)) - 1], np.int32)
+        return ("carry", ch, "outputs")
+
+    return round_fn, fed
+
+
+@pytest.mark.parametrize("script,want_rounds", [
+    # an all-pad wave probes straight to all -1: round 1 is its own input
+    ([[[-1, -1], [-1, -1]]], 1),
+    ([[[0, 1], [1, 1]], [[0, 0], [1, 1]], [[0, 0], [1, 1]]], 3),
+])
+def test_wave_fixpoint_stops_at_first_repeated_round(script, want_rounds):
+    round_fn, fed = _scripted_rounds(script)
+    rounds, out, ch = fast._wave_fixpoint(
+        round_fn, (2, 2), 0, ..., "wave-round", "max_rounds"
+    )
+    assert rounds == want_rounds
+    assert out[0] == "carry" and out[2] == "outputs"
+    np.testing.assert_array_equal(ch, script[want_rounds - 1])
+    # round 1 starts cold; every later round is fed its predecessor
+    assert len(fed) == want_rounds
+    np.testing.assert_array_equal(fed[0], np.full((2, 2), -1))
+    for r in range(1, want_rounds):
+        np.testing.assert_array_equal(fed[r], script[r - 1])
+
+
+def test_wave_fixpoint_counts_conflicts_from_round_two_in_live_slice():
+    script = [
+        # round 1 differs from the cold -1 everywhere: not a conflict
+        [[0, 1, 2, 3], [0, 1, 2, 3]],
+        # round 2: two changes inside the live slice [:1, :3], three
+        # outside it (the pad lane and the dead column)
+        [[1, 1, 0, 0], [1, 0, 0, 3]],
+        [[1, 1, 0, 0], [1, 0, 0, 3]],
+    ]
+    round_fn, _ = _scripted_rounds(script)
+    c0 = metrics.WAVE_CONFLICTS.value()
+    rounds, out, _ = fast._wave_fixpoint(
+        round_fn, (2, 4), 0, np.s_[:1, :3], "wave-round", "max_rounds"
+    )
+    assert rounds == 3 and out is not None
+    assert metrics.WAVE_CONFLICTS.value() == c0 + 2
+
+
+@pytest.mark.parametrize("reason", ["max_rounds", "universes_max_rounds"])
+def test_wave_fixpoint_falls_back_at_round_limit(reason):
+    # choices that flip every round never reach a fixpoint
+    round_fn, fed = _scripted_rounds([[[r % 2]] for r in range(1, 10)])
+    fb0 = metrics.WAVE_FALLBACKS.value(reason=reason)
+    rounds, out, _ = fast._wave_fixpoint(
+        round_fn, (1, 1), 4, ..., "wave-round", reason
+    )
+    assert (rounds, out) == (4, None)
+    assert len(fed) == 4
+    assert metrics.WAVE_FALLBACKS.value(reason=reason) == fb0 + 1
+
+
+# ---------------------------------------------------------------------------
+# The universes driver (`simon prove --engine wave`) against the serial scan
+# ---------------------------------------------------------------------------
+
+UNIVERSES = [
+    # contended (pods pile onto one roomy node), selector-pinned,
+    # GPU-share, cordoned, and a mixed universe
+    ("AA--", "ppppp"), ("AB--", "qqqqq"), ("C---", "rrrrr"),
+    ("DA--", "pqrpq"), ("ABCD", "rqprq"),
+]
+
+
+def _universes_inputs():
+    scope = semantics.SmallScope()
+    chunk = [semantics.Universe(n, p) for n, p in UNIVERSES]
+    return semantics._pack_chunk(
+        scope, chunk, fast.scenario_bucket(len(chunk))
+    )
+
+
+def _universes_host(out):
+    return (
+        {f: np.asarray(getattr(out[0], f)) for f in Carry._fields},
+    ) + tuple(np.asarray(a) for a in out[1:])
+
+
+def test_universes_wave_host_matches_schedule_universes():
+    # fresh inputs per engine: schedule_universes donates its carry
+    ref = _universes_host(fast.schedule_universes(*_universes_inputs()))
+    rounds0 = _hist_count(metrics.COMMIT_ROUNDS)
+    got = _universes_host(
+        fast.schedule_universes_wave_host(*_universes_inputs())
+    )
+    _assert_identical(got, ref)
+    assert _hist_count(metrics.COMMIT_ROUNDS) == rounds0 + 1
+    # the live pods both place and run out of room
+    live = ref[1][: len(UNIVERSES), : semantics.SmallScope.POD_SLOTS]
+    assert (live >= 0).any() and (live < 0).any()
+
+
+def test_universes_wave_host_overrun_falls_back_to_serial(monkeypatch):
+    ref = _universes_host(fast.schedule_universes(*_universes_inputs()))
+    calls = []
+
+    def never_converges(ns_s, carry_s, pods_s, weights_s, choices, f=None):
+        calls.append(1)
+        return (carry_s, np.asarray(choices) + 1)
+
+    monkeypatch.setattr(fast, "schedule_universes_wave", never_converges)
+    fb0 = metrics.WAVE_FALLBACKS.value(reason="universes_max_rounds")
+    got = _universes_host(
+        fast.schedule_universes_wave_host(*_universes_inputs())
+    )
+    _assert_identical(got, ref)
+    # the guard fires at round P + 2, one past the W + 1 guarantee
+    assert len(calls) == semantics.SmallScope.P_PAD + 2
+    assert metrics.WAVE_FALLBACKS.value(
+        reason="universes_max_rounds"
+    ) == fb0 + 1
 
 
 # ---------------------------------------------------------------------------
@@ -380,7 +514,7 @@ def test_preflight_budget_book_names_wave_entries():
     for entry in (
         "ops.fast:schedule_wave",
         "ops.fast:schedule_universes_wave",
-        "ops.fast:commit_choices",
+        "ops.kernels:commit_wave",
     ):
         assert entry in keys, f"{entry} missing from the preflight budgets"
 
@@ -396,7 +530,8 @@ def test_sarif_preflight_run_lists_covered_programs():
                 key="ops.fast:schedule_wave", error=None, estimate_ok=True
             ),
             types.SimpleNamespace(
-                key="ops.fast:commit_choices", error=None, estimate_ok=True
+                key="ops.fast:schedule_universes_wave", error=None,
+                estimate_ok=True,
             ),
         ],
         transfers=[],
@@ -406,5 +541,5 @@ def test_sarif_preflight_run_lists_covered_programs():
     run = sarif.preflight_run(report)
     assert run["results"] == []
     assert run["properties"]["programs"] == [
-        "ops.fast:commit_choices", "ops.fast:schedule_wave",
+        "ops.fast:schedule_universes_wave", "ops.fast:schedule_wave",
     ]
